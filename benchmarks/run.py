@@ -46,6 +46,8 @@ def main() -> None:
                     help="write a consolidated per-suite record to PATH")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from . import (change_detection, load_slo, obs_overhead,
                    query_latency, query_throughput, quantized_scan,
                    scrub_overhead, search_scaling, shard_scaling,

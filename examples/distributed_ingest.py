@@ -22,10 +22,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.embedder import HashProjectionEmbedder
 from repro.data.corpus import generate_corpus
 from repro.core.chunking import chunk_document
-from repro.launch.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 print(f"devices: {len(jax.devices())}")
-mesh = make_mesh((8,), ("shard",), axis_types=(AxisType.Auto,))
+mesh = jax.make_mesh((8,), ("shard",), axis_types=(AxisType.Auto,))
 
 # --- build a corpus and embed it (batched, host-side) -------------------
 corpus = generate_corpus(n_docs=30, n_versions=1, seed=3)

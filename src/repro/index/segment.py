@@ -240,6 +240,11 @@ class Segment:
         mask = self.alive if visible is None else (self.alive & visible)
         n_mask = int(mask.sum())
         if self.ivf is not None:
+            # the partition count grows as sqrt(rows), so a fixed nprobe
+            # would scan an ever smaller share of a merged segment and
+            # lose recall: probe at least an eighth of the partitions
+            # (8 of the 64 a memtable-sized segment has)
+            nprobe = max(nprobe, -(-self.ivf.centroids.shape[0] // 8))
             s, i, stats = self.ivf.search(q, k=k_eff, nprobe=nprobe,
                                           mask=mask)
             return s, i, int(round(stats.fraction_scanned * len(self)))

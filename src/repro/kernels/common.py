@@ -54,6 +54,53 @@ def pad_to(x, axis: int, multiple: int, value=0):
     return jnp.pad(x, widths, constant_values=value), n
 
 
+def block_topk(scores, k: int, idx_base):
+    """Top-k of each row of a (Q, bn) score block inside a kernel body.
+
+    k max passes (VPU reductions), rolled into a fori_loop so the lowered
+    graph stays O(1) in k. The k winners are kept in (Q, k) registers and
+    returned whole, so the caller stores each output block once: a store
+    at a dynamic lane offset inside the loop is refused by the TPU
+    compiler. Ties go to the lowest column, as ``jnp.argmax`` would.
+    Returns ((Q, k) scores, (Q, k) int32 row ids offset by ``idx_base``).
+    """
+    import jax.numpy as jnp
+    nq, bn = scores.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (nq, k), 1)
+
+    def body(t, carry):
+        s, top_s, top_i = carry
+        best = jnp.max(s, axis=1, keepdims=True)                   # (Q, 1)
+        arg = jnp.min(jnp.where(s == best, cols, bn), axis=1,
+                      keepdims=True)                               # (Q, 1)
+        top_s = jnp.where(slot == t, best, top_s)
+        top_i = jnp.where(slot == t, arg + idx_base, top_i)
+        return jnp.where(cols == arg, -jnp.inf, s), top_s, top_i
+
+    init = (scores, jnp.full((nq, k), -jnp.inf, jnp.float32),
+            jnp.zeros((nq, k), jnp.int32))
+    _, top_s, top_i = jax.lax.fori_loop(0, k, body, init)
+    return top_s, top_i
+
+
+def merge_blocks(s_blk, i_blk, k: int):
+    """Global merge of per-block candidates: (nblocks, Q, k) -> (Q, k)."""
+    import jax.numpy as jnp
+    nb, nq, kb = s_blk.shape
+    s_all = jnp.transpose(s_blk, (1, 0, 2)).reshape(nq, nb * kb)
+    i_all = jnp.transpose(i_blk, (1, 0, 2)).reshape(nq, nb * kb)
+    top_s, pos = jax.lax.top_k(s_all, k)
+    return top_s, jnp.take_along_axis(i_all, pos, axis=1)
+
+
+def row_block(n: int, bn: int) -> int:
+    """Corpus rows per grid step: ``bn``, or fewer for a small corpus,
+    always a multiple of 128 so every block is lane-aligned on the chip
+    (the wrapper pads the corpus to a whole number of blocks)."""
+    return min(bn, -(-max(n, 1) // 128) * 128)
+
+
 def split_i64(x):
     """Split non-negative int64 (numpy, host-side) into (hi:int32,
     lo:uint32) device arrays — TPUs are 32-bit machines and JAX x64 is off;

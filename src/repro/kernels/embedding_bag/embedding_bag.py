@@ -25,7 +25,7 @@ def _kernel(idx_ref, w_ref, table_ref, o_ref, *, bag: int, combiner: str):
         idx = idx_ref[0, j]
         valid = idx >= 0
         safe = jnp.where(valid, idx, 0)
-        row = pl.load(table_ref, (pl.dslice(safe, 1), slice(None)))  # (1, d)
+        row = table_ref[pl.ds(safe, 1), :]                         # (1, d)
         w = jnp.where(valid, w_ref[0, j], 0.0)
         acc = acc + w * row[0].astype(jnp.float32)
         wsum = wsum + w

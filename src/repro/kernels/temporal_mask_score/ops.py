@@ -1,10 +1,11 @@
 """jit'd wrappers for the temporal validity-masked top-k kernel.
 
 ``temporal_window_topk`` is the general fused primitive: one dispatch
-scores a (Q, d) query block against a device-resident full-history corpus
-with a PER-QUERY validity window — no per-timestamp materialized snapshot
-copy ever exists. ``temporal_topk`` (point-in-time, one shared ts) is the
-degenerate window [ts, ts+1).
+scores a (Q, d) query block against the full-history corpus with a
+PER-QUERY validity window — no per-timestamp materialized snapshot copy
+ever exists. The history is sent host->device on every call.
+``temporal_topk`` (point-in-time, one shared ts) is the degenerate
+window [ts, ts+1).
 """
 from __future__ import annotations
 
@@ -15,47 +16,50 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
-from ..common import kernel_mode, kernel_mode_q8, lt_i64, pad_to, split_i64
+from ..common import (kernel_mode, kernel_mode_q8, merge_blocks, pad_to,
+                      row_block, split_i64)
 from .ref import temporal_window_topk_q8_ref, temporal_window_topk_ref
-from .temporal_mask_score import (temporal_block_candidates,
-                                  temporal_block_candidates_q8)
+from .temporal_mask_score import temporal_block_candidates
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bn", "mode"))
-def _temporal_topk_jit(q, corpus, vf_hi, vf_lo, vt_hi, vt_lo,
-                       t0_hi, t0_lo, t1_hi, t1_lo,
-                       k: int, bn: int, mode: str):
-    if mode == "ref_jnp":
-        # jnp variant of the oracle (used on-device; exact via split i64)
-        valid = lt_i64(vf_hi[None, :], vf_lo.astype(jnp.uint32)[None, :],
-                       t1_hi[:, None], t1_lo.astype(jnp.uint32)[:, None]) & \
-            lt_i64(t0_hi[:, None], t0_lo.astype(jnp.uint32)[:, None],
-                   vt_hi[None, :], vt_lo.astype(jnp.uint32)[None, :])
-        scores = jnp.dot(q, corpus.T)
-        scores = jnp.where(valid, scores, -jnp.inf)
-        top_s, top_i = jax.lax.top_k(scores, k)
-        return top_s, top_i.astype(jnp.int32)
-    corpus_p, _ = pad_to(corpus, 0, bn)
-    pad = lambda a, v: pad_to(a, 0, bn, value=v)[0]
-    # padded rows: empty validity interval (vf=max, vt=0) => always invalid
-    vf_hi_p, vf_lo_p = pad(vf_hi, np.int32(0x7FFFFFFF)), pad(vf_lo, -1)
-    vt_hi_p, vt_lo_p = pad(vt_hi, 0), pad(vt_lo, 0)
-    s_blk, i_blk = temporal_block_candidates(
-        q, corpus_p, vf_hi_p, vf_lo_p, vt_hi_p, vt_lo_p,
-        t0_hi, t0_lo, t1_hi, t1_lo, k, bn=bn,
-        interpret=(mode == "interpret"))
-    nb = s_blk.shape[0]
-    s_all = jnp.transpose(s_blk, (1, 0, 2)).reshape(q.shape[0], nb * k)
-    i_all = jnp.transpose(i_blk, (1, 0, 2)).reshape(q.shape[0], nb * k)
-    top_s, pos = jax.lax.top_k(s_all, k)
-    top_i = jnp.take_along_axis(i_all, pos, axis=1)
-    return top_s, top_i
+# a padded row's validity interval is empty: it starts after every window
+# and ends before every window, so it never overlaps one
+_PAD_FROM = np.iinfo(np.int64).max
+_PAD_TO = 0
 
 
-def _split_dev(x_i64: np.ndarray):
-    """Host int64 -> (hi int32, lo int32-carrier) device arrays."""
+def _split_flip(x_i64: np.ndarray) -> np.ndarray:
+    """Host int64 -> (2, n) int32 words (hi, lo ^ 2**31). Flipping the
+    low word's top bit makes a signed compare order it like the unsigned
+    word, so the kernel compares int32 pairs lexicographically."""
     hi, lo = split_i64(x_i64)
-    return jnp.asarray(hi), jnp.asarray(lo.view(np.int32))
+    return np.stack([hi, (lo ^ np.uint32(1 << 31)).view(np.int32)])
+
+
+def _device_words(valid_from, valid_to, t0s, t1s, bn: int):
+    """Validity words (4, N padded to bn) and window words (Q, 4)."""
+    pad = (-len(valid_from)) % bn
+    vf = np.concatenate([np.asarray(valid_from, np.int64),
+                         np.full(pad, _PAD_FROM, np.int64)])
+    vt = np.concatenate([np.asarray(valid_to, np.int64),
+                         np.full(pad, _PAD_TO, np.int64)])
+    valid = np.concatenate([_split_flip(vf), _split_flip(vt)])
+    win = np.concatenate([_split_flip(t0s), _split_flip(t1s)]).T
+    return jnp.asarray(valid), jnp.asarray(np.ascontiguousarray(win))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "bn", "interpret", "q8"))
+def _temporal_topk_jit(q, corpus, valid, win, k: int, bn: int,
+                       interpret: bool, q8: bool):
+    corpus_p, _ = pad_to(corpus, 0, bn)
+    s_blk, i_blk = temporal_block_candidates(
+        q, corpus_p, valid, win, k, bn=bn, interpret=interpret)
+    top_s, top_i = merge_blocks(s_blk, i_blk, k)
+    if q8:
+        # contract: an empty (-inf) pool slot is idx -1, so a downstream
+        # exact rescore can never resurrect an out-of-window row
+        top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
+    return top_s, top_i
 
 
 def temporal_window_topk(q, corpus, valid_from, valid_to, t0s, t1s, k: int,
@@ -84,37 +88,11 @@ def temporal_window_topk(q, corpus, valid_from, valid_to, t0s, t1s, k: int,
         if mode == "ref":
             return temporal_window_topk_ref(q, corpus, valid_from,
                                             valid_to, t0s, t1s, k)
-        vf_hi, vf_lo = _split_dev(valid_from)
-        vt_hi, vt_lo = _split_dev(valid_to)
-        t0_hi, t0_lo = _split_dev(t0s)
-        t1_hi, t1_lo = _split_dev(t1s)
-        bn = int(min(bn, max(128, corpus.shape[0])))
+        bn = row_block(int(corpus.shape[0]), bn)
+        valid, win = _device_words(valid_from, valid_to, t0s, t1s, bn)
         return _temporal_topk_jit(
-            jnp.asarray(q), jnp.asarray(corpus, jnp.float32),
-            vf_hi, vf_lo, vt_hi, vt_lo, t0_hi, t0_lo, t1_hi, t1_lo,
-            k, bn, mode)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "bn", "interpret"))
-def _temporal_topk_q8_jit(qs, c8, vf_hi, vf_lo, vt_hi, vt_lo,
-                          t0_hi, t0_lo, t1_hi, t1_lo,
-                          k: int, bn: int, interpret: bool):
-    c8_p, _ = pad_to(c8, 0, bn)
-    pad = lambda a, v: pad_to(a, 0, bn, value=v)[0]
-    # padded rows: empty validity interval (vf=max, vt=0) => always invalid
-    vf_hi_p, vf_lo_p = pad(vf_hi, np.int32(0x7FFFFFFF)), pad(vf_lo, -1)
-    vt_hi_p, vt_lo_p = pad(vt_hi, 0), pad(vt_lo, 0)
-    s_blk, i_blk = temporal_block_candidates_q8(
-        qs, c8_p, vf_hi_p, vf_lo_p, vt_hi_p, vt_lo_p,
-        t0_hi, t0_lo, t1_hi, t1_lo, k, bn=bn, interpret=interpret)
-    nb = s_blk.shape[0]
-    s_all = jnp.transpose(s_blk, (1, 0, 2)).reshape(qs.shape[0], nb * k)
-    i_all = jnp.transpose(i_blk, (1, 0, 2)).reshape(qs.shape[0], nb * k)
-    top_s, pos = jax.lax.top_k(s_all, k)
-    top_i = jnp.take_along_axis(i_all, pos, axis=1)
-    # contract: an empty (-inf) pool slot is idx -1 in EVERY mode, so a
-    # downstream exact rescore can never resurrect an out-of-window row
-    return top_s, jnp.where(jnp.isfinite(top_s), top_i, -1)
+            jnp.asarray(q), jnp.asarray(corpus, jnp.float32), valid, win,
+            k, bn, mode == "interpret", False)
 
 
 def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
@@ -156,15 +134,11 @@ def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
                 & (t0s[:, None] < vt[None, :])
             scores[~valid] = -np.inf
             return pool_topk_host(scores, k)
-        vf_hi, vf_lo = _split_dev(vf)
-        vt_hi, vt_lo = _split_dev(vt)
-        t0_hi, t0_lo = _split_dev(t0s)
-        t1_hi, t1_lo = _split_dev(t1s)
-        bn = int(min(bn, max(128, c8.shape[0])))
-        return _temporal_topk_q8_jit(
-            jnp.asarray(qs), jnp.asarray(c8),
-            vf_hi, vf_lo, vt_hi, vt_lo, t0_hi, t0_lo, t1_hi, t1_lo,
-            k, bn, mode == "interpret")
+        bn = row_block(int(c8.shape[0]), bn)
+        valid, win = _device_words(vf, vt, t0s, t1s, bn)
+        return _temporal_topk_jit(
+            jnp.asarray(qs), jnp.asarray(c8), valid, win,
+            k, bn, mode == "interpret", True)
 
 
 def temporal_topk(q, corpus, valid_from, valid_to, ts: int, k: int,

@@ -10,18 +10,26 @@ half-open window [t0_q, t1_q):
 evaluated INSIDE the kernel, before any score can enter the top-k
 selection — an invalid (future/superseded/deleted) chunk is -inf before
 ranking, so temporal leakage is impossible by construction even when the
-full version history is device-resident. A point-in-time query at ts is
-the window [ts, ts+1) — with integer-microsecond timestamps the overlap
-test degenerates to exactly valid_from <= ts < valid_to.
+full version history is scanned. A point-in-time query at ts is the
+window [ts, ts+1) — with integer-microsecond timestamps the overlap test
+degenerates to exactly valid_from <= ts < valid_to.
 
 Per-query bounds mean one dispatch serves a whole batch of queries with
-DIFFERENT target instants/windows over one resident full-history corpus:
-the mask is (Q, bn), not (bn,).
+DIFFERENT target instants/windows over one full-history corpus: the mask
+is (Q, bn), not (bn,).
 
-Timestamps are int64 on the host; TPUs are 32-bit machines, so validity
-columns and window bounds arrive as split (hi: int32, lo: uint32) pairs
-and the interval test is a lexicographic compare — exact at microsecond
-resolution (see kernels/common.split_i64).
+Timestamps are int64 on the host; TPUs are 32-bit machines, so each
+timestamp arrives as an int32 pair (hi, lo ^ 2**31) — the low word with
+its top bit flipped, so a SIGNED compare of it orders like the unsigned
+low word — and the interval test is a lexicographic compare, exact at
+microsecond resolution (see ops._split_flip). The four validity words
+of each row ride as one (4, N) int32 array and the four window words of
+each query as one (Q, 4) array, so every block is 2-D and lane-aligned.
+
+One body serves the fp32 and the int8 history (DESIGN.md §11): the int8
+block is dequantized IN-REGISTER by the astype (a no-op for fp32) and
+the per-dimension scale is folded into the fp32 queries by the wrapper.
+The temporal-leakage guard is the same for both.
 """
 from __future__ import annotations
 
@@ -31,110 +39,49 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import lt_i64
+from ..common import block_topk, lt_i64
 
 
-def _kernel(q_ref, c_ref, vf_hi_ref, vf_lo_ref, vt_hi_ref, vt_lo_ref,
-            t0_hi_ref, t0_lo_ref, t1_hi_ref, t1_lo_ref,
-            out_s_ref, out_i_ref, *, k: int, bn: int):
-    j = pl.program_id(0)
-    scores = jax.lax.dot_general(
-        q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (Q, bn)
-
-    vf_hi, vf_lo = vf_hi_ref[...], vf_lo_ref[...].astype(jnp.uint32)
-    vt_hi, vt_lo = vt_hi_ref[...], vt_lo_ref[...].astype(jnp.uint32)
-    t0_hi, t0_lo = t0_hi_ref[...], t0_lo_ref[...].astype(jnp.uint32)
-    t1_hi, t1_lo = t1_hi_ref[...], t1_lo_ref[...].astype(jnp.uint32)
-    # THE temporal-leakage guard: window overlap, pre-ranking, per query.
-    # (vf[None, :] vs t1[:, None]) broadcasts to the full (Q, bn) mask.
-    valid = lt_i64(vf_hi[None, :], vf_lo[None, :],
-                   t1_hi[:, None], t1_lo[:, None]) & \
-        lt_i64(t0_hi[:, None], t0_lo[:, None],
-               vt_hi[None, :], vt_lo[None, :])
-    scores = jnp.where(valid, scores, -jnp.inf)
-
-    idx_base = (j * bn).astype(jnp.int32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    # unit dslice on the block axis (not a bare int): integer indexers are
-    # rejected by the interpret-mode store discharge rule
-    def body(t, s):
-        best = jnp.max(s, axis=1)
-        arg = jnp.argmax(s, axis=1).astype(jnp.int32)
-        pl.store(out_s_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 best[None, :, None])
-        pl.store(out_i_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 (arg + idx_base)[None, :, None])
-        return jnp.where(cols == arg[:, None], -jnp.inf, s)
-
-    jax.lax.fori_loop(0, k, body, scores)
-
-
-def _kernel_q8(q_ref, c_ref, vf_hi_ref, vf_lo_ref, vt_hi_ref, vt_lo_ref,
-               t0_hi_ref, t0_lo_ref, t1_hi_ref, t1_lo_ref,
-               out_s_ref, out_i_ref, *, k: int, bn: int):
-    """int8-corpus variant (DESIGN.md §11): the resident full-history
-    block streams as int8 (4x less HBM traffic on the path whose cost
-    the temporal tier's latency bound rests on) and is dequantized
-    IN-REGISTER; the per-dimension scale is folded into the fp32 queries
-    by the wrapper. The temporal-leakage guard is UNCHANGED: the
-    per-query window-overlap test still runs before any score can enter
-    the top-k selection."""
-    j = pl.program_id(0)
+def _kernel(q_ref, c_ref, valid_ref, win_ref, out_s_ref, out_i_ref, *,
+            k: int):
+    bn = c_ref.shape[0]
     scores = jax.lax.dot_general(
         q_ref[...], c_ref[...].astype(jnp.float32),
-        (((1,), (1,)), ((), ())),
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)              # (Q, bn)
-
-    vf_hi, vf_lo = vf_hi_ref[...], vf_lo_ref[...].astype(jnp.uint32)
-    vt_hi, vt_lo = vt_hi_ref[...], vt_lo_ref[...].astype(jnp.uint32)
-    t0_hi, t0_lo = t0_hi_ref[...], t0_lo_ref[...].astype(jnp.uint32)
-    t1_hi, t1_lo = t1_hi_ref[...], t1_lo_ref[...].astype(jnp.uint32)
-    valid = lt_i64(vf_hi[None, :], vf_lo[None, :],
-                   t1_hi[:, None], t1_lo[:, None]) & \
-        lt_i64(t0_hi[:, None], t0_lo[:, None],
-               vt_hi[None, :], vt_lo[None, :])
+    # validity rows (1, bn): valid_from hi/lo, valid_to hi/lo
+    vf_hi, vf_lo = valid_ref[0:1, :], valid_ref[1:2, :]
+    vt_hi, vt_lo = valid_ref[2:3, :], valid_ref[3:4, :]
+    # window columns (Q, 1): t0 hi/lo, t1 hi/lo
+    t0_hi, t0_lo = win_ref[:, 0:1], win_ref[:, 1:2]
+    t1_hi, t1_lo = win_ref[:, 2:3], win_ref[:, 3:4]
+    # THE temporal-leakage guard: window overlap, pre-ranking, per query.
+    valid = lt_i64(vf_hi, vf_lo, t1_hi, t1_lo) & \
+        lt_i64(t0_hi, t0_lo, vt_hi, vt_lo)               # (Q, bn)
     scores = jnp.where(valid, scores, -jnp.inf)
-
-    idx_base = (j * bn).astype(jnp.int32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    def body(t, s):
-        best = jnp.max(s, axis=1)
-        arg = jnp.argmax(s, axis=1).astype(jnp.int32)
-        pl.store(out_s_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 best[None, :, None])
-        pl.store(out_i_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 (arg + idx_base)[None, :, None])
-        return jnp.where(cols == arg[:, None], -jnp.inf, s)
-
-    jax.lax.fori_loop(0, k, body, scores)
+    top_s, top_i = block_topk(scores, k, pl.program_id(0) * bn)
+    out_s_ref[...] = top_s[None]
+    out_i_ref[...] = top_i[None]
 
 
-def temporal_block_candidates(q, corpus, vf_hi, vf_lo, vt_hi, vt_lo,
-                              t0_hi, t0_lo, t1_hi, t1_lo,
-                              k: int, bn: int = 512, interpret: bool = False):
-    """Per-block streaming candidates. q: (Q, d); corpus: (N, d) with
-    N % bn == 0; vf/vt pairs: (N,); t0/t1 pairs: (Q,) per-query window
-    bounds. Returns ((N//bn, Q, k) scores, (N//bn, Q, k) global indices).
+def temporal_block_candidates(q, corpus, valid, win, k: int, bn: int = 512,
+                              interpret: bool = False):
+    """Per-block streaming candidates. q: (Q, d) fp32 (scale-folded for
+    an int8 history); corpus: (N, d) fp32 or int8 with N % bn == 0;
+    valid: (4, N) int32 validity words; win: (Q, 4) int32 window words.
+    Returns ((N//bn, Q, k) scores, (N//bn, Q, k) global indices).
     """
     n, d = corpus.shape
     nq = q.shape[0]
-    assert n % bn == 0
-    kern = functools.partial(_kernel, k=k, bn=bn)
-    blk1 = lambda j: (j,)
-    qrow = lambda j: (0,)
+    assert n % bn == 0, (n, bn)
     return pl.pallas_call(
-        kern,
+        functools.partial(_kernel, k=k),
         grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((nq, d), lambda j: (0, 0)),
-            pl.BlockSpec((bn, d), lambda j: (j, 0)),
-            pl.BlockSpec((bn,), blk1), pl.BlockSpec((bn,), blk1),
-            pl.BlockSpec((bn,), blk1), pl.BlockSpec((bn,), blk1),
-            pl.BlockSpec((nq,), qrow), pl.BlockSpec((nq,), qrow),
-            pl.BlockSpec((nq,), qrow), pl.BlockSpec((nq,), qrow),
+            pl.BlockSpec((nq, d), lambda j: (0, 0)),     # queries: resident
+            pl.BlockSpec((bn, d), lambda j: (j, 0)),     # history block
+            pl.BlockSpec((4, bn), lambda j: (0, j)),     # validity words
+            pl.BlockSpec((nq, 4), lambda j: (0, 0)),     # windows: resident
         ],
         out_specs=[
             pl.BlockSpec((1, nq, k), lambda j: (j, 0, 0)),
@@ -145,40 +92,4 @@ def temporal_block_candidates(q, corpus, vf_hi, vf_lo, vt_hi, vt_lo,
             jax.ShapeDtypeStruct((n // bn, nq, k), jnp.int32),
         ],
         interpret=interpret,
-    )(q, corpus, vf_hi, vf_lo, vt_hi, vt_lo, t0_hi, t0_lo, t1_hi, t1_lo)
-
-
-def temporal_block_candidates_q8(qs, c8, vf_hi, vf_lo, vt_hi, vt_lo,
-                                 t0_hi, t0_lo, t1_hi, t1_lo,
-                                 k: int, bn: int = 512,
-                                 interpret: bool = False):
-    """Quantized-corpus streaming candidates. ``qs``: (Q, d) fp32 with
-    the quantization scale folded in; ``c8``: (N, d) int8 with
-    N % bn == 0; validity/window pairs exactly as the fp32 variant."""
-    n, d = c8.shape
-    nq = qs.shape[0]
-    assert n % bn == 0
-    kern = functools.partial(_kernel_q8, k=k, bn=bn)
-    blk1 = lambda j: (j,)
-    qrow = lambda j: (0,)
-    return pl.pallas_call(
-        kern,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((nq, d), lambda j: (0, 0)),
-            pl.BlockSpec((bn, d), lambda j: (j, 0)),
-            pl.BlockSpec((bn,), blk1), pl.BlockSpec((bn,), blk1),
-            pl.BlockSpec((bn,), blk1), pl.BlockSpec((bn,), blk1),
-            pl.BlockSpec((nq,), qrow), pl.BlockSpec((nq,), qrow),
-            pl.BlockSpec((nq,), qrow), pl.BlockSpec((nq,), qrow),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nq, k), lambda j: (j, 0, 0)),
-            pl.BlockSpec((1, nq, k), lambda j: (j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n // bn, nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, nq, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(qs, c8, vf_hi, vf_lo, vt_hi, vt_lo, t0_hi, t0_lo, t1_hi, t1_lo)
+    )(q, corpus, valid, win)

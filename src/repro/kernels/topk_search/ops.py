@@ -9,26 +9,28 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
-from ..common import kernel_mode, kernel_mode_q8, pad_to
+from ..common import (kernel_mode, kernel_mode_q8, merge_blocks, pad_to,
+                      row_block)
 from .ref import topk_search_q8_ref, topk_search_ref
-from .topk_search import topk_block_candidates, topk_block_candidates_q8
+from .topk_search import topk_block_candidates
+
+
+def _scan(q, corpus, mask, k: int, bn: int, interpret: bool):
+    """Kernel path shared by the fp32 and int8 wrappers: pad the corpus
+    to whole blocks (padded rows inactive), carry the mask as a (1, N)
+    int32 row, then merge the per-block candidates."""
+    corpus_p, _ = pad_to(corpus, 0, bn)
+    mask_p, _ = pad_to(mask.astype(jnp.int32), 0, bn)
+    s_blk, i_blk = topk_block_candidates(
+        q, corpus_p, mask_p[None, :], k, bn=bn, interpret=interpret)
+    return merge_blocks(s_blk, i_blk, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bn", "mode"))
 def _topk_search_jit(q, corpus, mask, k: int, bn: int, mode: str):
     if mode == "ref":
         return topk_search_ref(q, corpus, mask, k)
-    corpus_p, n = pad_to(corpus, 0, bn)
-    mask_p, _ = pad_to(mask, 0, bn, value=False)
-    s_blk, i_blk = topk_block_candidates(
-        q, corpus_p, mask_p, k, bn=bn, interpret=(mode == "interpret"))
-    # global merge: (nblocks, Q, k) -> (Q, nblocks*k) -> top-k
-    nb = s_blk.shape[0]
-    s_all = jnp.transpose(s_blk, (1, 0, 2)).reshape(q.shape[0], nb * k)
-    i_all = jnp.transpose(i_blk, (1, 0, 2)).reshape(q.shape[0], nb * k)
-    top_s, pos = jax.lax.top_k(s_all, k)
-    top_i = jnp.take_along_axis(i_all, pos, axis=1)
-    return top_s, top_i
+    return _scan(q, corpus, mask, k, bn, mode == "interpret")
 
 
 def topk_search(q, corpus, mask, k: int, bn: int = 512,
@@ -44,7 +46,7 @@ def topk_search(q, corpus, mask, k: int, bn: int = 512,
         corpus = jnp.asarray(corpus, jnp.float32)
         mask = jnp.asarray(mask, bool)
         k = int(min(k, corpus.shape[0]))
-        bn = int(min(bn, max(128, corpus.shape[0])))
+        bn = row_block(int(corpus.shape[0]), bn)
         sp.add("rows", int(corpus.shape[0]))
         sp.add("bytes_streamed", int(corpus.shape[0]) * int(corpus.shape[1]) * 4)
         return _topk_search_jit(q, corpus, mask, k, bn, kernel_mode(mode))
@@ -55,15 +57,7 @@ def _topk_search_q8_jit(qs, c8, mask, k: int, bn: int, mode: str):
     if mode == "ref":
         top_s, top_i = topk_search_q8_ref(qs, c8, mask, k)
         return top_s, jnp.where(jnp.isfinite(top_s), top_i, -1)
-    c8_p, _ = pad_to(c8, 0, bn)
-    mask_p, _ = pad_to(mask, 0, bn, value=False)
-    s_blk, i_blk = topk_block_candidates_q8(
-        qs, c8_p, mask_p, k, bn=bn, interpret=(mode == "interpret"))
-    nb = s_blk.shape[0]
-    s_all = jnp.transpose(s_blk, (1, 0, 2)).reshape(qs.shape[0], nb * k)
-    i_all = jnp.transpose(i_blk, (1, 0, 2)).reshape(qs.shape[0], nb * k)
-    top_s, pos = jax.lax.top_k(s_all, k)
-    top_i = jnp.take_along_axis(i_all, pos, axis=1)
+    top_s, top_i = _scan(qs, c8, mask, k, bn, mode == "interpret")
     # contract: an empty (-inf) pool slot is idx -1 in EVERY mode, so a
     # downstream exact rescore can never resurrect a masked row
     return top_s, jnp.where(jnp.isfinite(top_s), top_i, -1)
@@ -104,6 +98,6 @@ def topk_search_q8(q, c8, scale, mask, k: int, bn: int = 512,
             scores = asym_scores_host(qs, c8)
             scores[:, ~np.asarray(mask, bool)] = -np.inf
             return pool_topk_host(scores, k)
-        bn = int(min(bn, max(128, c8.shape[0])))
+        bn = row_block(int(c8.shape[0]), bn)
         return _topk_search_q8_jit(jnp.asarray(qs), jnp.asarray(c8),
                                    jnp.asarray(mask, bool), k, bn, mode)

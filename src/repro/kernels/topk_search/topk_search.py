@@ -4,14 +4,20 @@ query hot path; DESIGN.md §2).
 The (Q, N) score matrix is NEVER materialized in HBM: corpus blocks of
 ``bn`` rows stream through VMEM; each grid step computes Q x bn scores on
 the MXU, masks inactive slots, and reduces them to a per-block top-k via k
-iterative max/argmax passes (VPU reductions — k is small and static).
+iterative max passes (kernels/common.block_topk — k is small and static).
 Per-block candidates land in a (nblocks, Q, k) output; the cheap global
 merge over nblocks*k candidates happens in the jit'd wrapper (ops.py).
 
+One body serves the fp32 and the int8 corpus (DESIGN.md §11): an int8
+block arrives at 1 byte/element of HBM->VMEM traffic instead of 4 (the
+scan is bandwidth-bound, so this is the whole win) and is dequantized
+IN-REGISTER by the astype, a no-op for fp32; the per-dimension
+quantization scale is already folded into the fp32 queries by the
+wrapper, so the dot IS the exact dequantized asymmetric distance.
+
 VMEM working set per step: Q*D (queries, resident) + bn*D (corpus block)
 + Q*bn (scores) floats. Defaults (Q<=256, D=384, bn=512) ~= 1.7 MB — far
-inside the ~16 MB/core VMEM budget; dims padded to multiples of 128 for
-MXU alignment by the wrapper.
+inside the ~16 MB/core VMEM budget.
 """
 from __future__ import annotations
 
@@ -21,85 +27,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import block_topk
 
-def _kernel(q_ref, c_ref, mask_ref, out_s_ref, out_i_ref, *, k: int, bn: int):
-    j = pl.program_id(0)
-    q = q_ref[...]                       # (Q, D)
-    c = c_ref[...]                       # (bn, D)
+
+def _kernel(q_ref, c_ref, mask_ref, out_s_ref, out_i_ref, *, k: int):
+    bn = c_ref.shape[0]
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())),
+        q_ref[...], c_ref[...].astype(jnp.float32),
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)              # (Q, bn)
-    active = mask_ref[...]                               # (bn,) bool
-    scores = jnp.where(active[None, :], scores, -jnp.inf)
-
-    idx_base = (j * bn).astype(jnp.int32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    # streaming top-k: k max/argmax passes (VPU reductions), rolled into a
-    # fori_loop so the lowered graph stays O(1) in k. The leading block axis
-    # is indexed with a unit dslice, not a bare int: integer indexers are
-    # rejected by the interpret-mode store discharge rule.
-    def body(t, s):
-        best = jnp.max(s, axis=1)
-        arg = jnp.argmax(s, axis=1).astype(jnp.int32)
-        pl.store(out_s_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 best[None, :, None])
-        pl.store(out_i_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 (arg + idx_base)[None, :, None])
-        return jnp.where(cols == arg[:, None], -jnp.inf, s)
-
-    jax.lax.fori_loop(0, k, body, scores)
-
-
-def _kernel_q8(q_ref, c_ref, mask_ref, out_s_ref, out_i_ref, *, k: int,
-               bn: int):
-    """int8-corpus variant (DESIGN.md §11): the corpus block arrives as
-    int8 (1 byte/element of HBM->VMEM traffic instead of 4 — the scan is
-    bandwidth-bound, so this is the whole win) and is dequantized
-    IN-REGISTER by the astype; the per-dimension quantization scale is
-    already folded into the fp32 queries by the wrapper, so the dot
-    below IS the exact dequantized asymmetric distance."""
-    j = pl.program_id(0)
-    q = q_ref[...]                                       # (Q, D) fp32
-    c = c_ref[...].astype(jnp.float32)                   # (bn, D) int8 -> f32
-    scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (Q, bn)
-    active = mask_ref[...]
-    scores = jnp.where(active[None, :], scores, -jnp.inf)
-
-    idx_base = (j * bn).astype(jnp.int32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-
-    def body(t, s):
-        best = jnp.max(s, axis=1)
-        arg = jnp.argmax(s, axis=1).astype(jnp.int32)
-        pl.store(out_s_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 best[None, :, None])
-        pl.store(out_i_ref, (pl.dslice(0, 1), slice(None), pl.dslice(t, 1)),
-                 (arg + idx_base)[None, :, None])
-        return jnp.where(cols == arg[:, None], -jnp.inf, s)
-
-    jax.lax.fori_loop(0, k, body, scores)
+    scores = jnp.where(mask_ref[...] != 0, scores, -jnp.inf)   # (1, bn) mask
+    top_s, top_i = block_topk(scores, k, pl.program_id(0) * bn)
+    out_s_ref[...] = top_s[None]
+    out_i_ref[...] = top_i[None]
 
 
 def topk_block_candidates(q: jax.Array, corpus: jax.Array, mask: jax.Array,
                           k: int, bn: int = 512,
                           interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Stage 1: per-corpus-block top-k. corpus (N, D) with N % bn == 0.
+    """Stage 1: per-corpus-block top-k. q: (Q, D) fp32 (scale-folded for
+    an int8 corpus); corpus: (N, D) fp32 or int8 with N % bn == 0; mask:
+    (1, N) int32, nonzero = active. The mask rides as a 2-D row so its
+    blocks tile like the corpus lanes on the chip.
     Returns (scores (nblocks, Q, k), idx (nblocks, Q, k))."""
     n, d = corpus.shape
     nq = q.shape[0]
     assert n % bn == 0, (n, bn)
-    grid = (n // bn,)
-    kern = functools.partial(_kernel, k=k, bn=bn)
     return pl.pallas_call(
-        kern,
-        grid=grid,
+        functools.partial(_kernel, k=k),
+        grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((nq, d), lambda j: (0, 0)),     # queries: resident
             pl.BlockSpec((bn, d), lambda j: (j, 0)),     # corpus block stream
-            pl.BlockSpec((bn,), lambda j: (j,)),         # active mask block
+            pl.BlockSpec((1, bn), lambda j: (0, j)),     # active mask block
         ],
         out_specs=[
             pl.BlockSpec((1, nq, k), lambda j: (j, 0, 0)),
@@ -111,35 +71,3 @@ def topk_block_candidates(q: jax.Array, corpus: jax.Array, mask: jax.Array,
         ],
         interpret=interpret,
     )(q, corpus, mask)
-
-
-def topk_block_candidates_q8(qs: jax.Array, c8: jax.Array, mask: jax.Array,
-                             k: int, bn: int = 512, interpret: bool = False
-                             ) -> tuple[jax.Array, jax.Array]:
-    """Stage 1 of the quantized scan: per-block top-k over an int8
-    corpus. ``qs`` is the (Q, D) fp32 query block with the per-dimension
-    quantization scale already folded in; ``c8`` is (N, D) int8 with
-    N % bn == 0. Same streaming BlockSpec shape as the fp32 kernel —
-    only the corpus byte width changes."""
-    n, d = c8.shape
-    nq = qs.shape[0]
-    assert n % bn == 0, (n, bn)
-    kern = functools.partial(_kernel_q8, k=k, bn=bn)
-    return pl.pallas_call(
-        kern,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((nq, d), lambda j: (0, 0)),     # queries: resident
-            pl.BlockSpec((bn, d), lambda j: (j, 0)),     # int8 block stream
-            pl.BlockSpec((bn,), lambda j: (j,)),         # active mask block
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nq, k), lambda j: (j, 0, 0)),
-            pl.BlockSpec((1, nq, k), lambda j: (j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n // bn, nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((n // bn, nq, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(qs, c8, mask)

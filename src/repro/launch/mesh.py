@@ -11,20 +11,21 @@ host devices via XLA_FLAGS before any jax import).
 """
 from __future__ import annotations
 
-from .compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over the locally available devices (tests / examples)."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

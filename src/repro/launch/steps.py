@@ -25,7 +25,6 @@ from ..models import schnet as schnet_m
 from ..models import transformer as tfm
 from ..train.optimizer import Optimizer, adafactor, adamw
 from . import sharding as shd
-from .compat import shard_map
 
 ADAFACTOR_THRESHOLD = 100e9        # params above this use factored state
 
@@ -352,7 +351,7 @@ def _recsys_bundle(arch_name: str, shape: str, reduced: bool) -> CellBundle:
 
             # outputs ARE replicated (post-all_gather merge) but the
             # static varying-axis checker can't prove it
-            return shard_map(
+            return jax.shard_map(
                 local_fn, mesh=mesh,
                 in_specs=({"query": P(), "candidates": P(every, None),
                            "candidate_mask": P(every)},),

@@ -23,7 +23,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..launch.compat import shard_map
 from .layers import dense_init
 
 
@@ -246,7 +245,7 @@ def moe_block_sharded(p, x, cfg: MoEConfig, mesh,
         return out.reshape(bl, sl, d), aux
 
     dp_spec = dp if len(dp) > 1 else dp[0]
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(None, None),
                   P("model", "data", None), P("model", "data", None)),

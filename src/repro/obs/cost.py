@@ -11,12 +11,12 @@ memory roofline: buy bandwidth or shrink bytes), **dispatch-bound**
 planning, merging — batch harder), or **queue-bound** (the request
 mostly waited for admission/dispatch: shed load or add capacity).
 
-The peak mirrors ``benchmarks/roofline.py`` (HBM_BW = 819e9 B/s, a
-v5p-class figure; src must not import from benchmarks/, so the constant
-is duplicated and cross-checked by a test). On CPU-interpret runs the
-achieved fraction is tiny — the point is the RELATIVE attribution, and
-that a device-backed deployment can read real roofline numbers from the
-same spans.
+The roofline fraction needs the peak HBM bandwidth of the chip the
+trace ran on, looked up by JAX's ``device_kind`` in ``PEAK_HBM_GBS``. A
+kind not in the table (the CPU among them) gets ``achieved_gbs`` and no
+fraction: no peak is assumed. The span's wall time is host time, so the
+fraction is an end-to-end bound, not a kernel's device-time roofline
+share.
 
 Annotation happens on SERIALIZED trace dicts (the flight recorder's
 retained records), never on the hot path: serving pays for the raw
@@ -24,23 +24,33 @@ counters only.
 """
 from __future__ import annotations
 
-# Mirrors benchmarks/roofline.py HBM_BW (819e9 B/s) — asserted equal in
+# Peak HBM bandwidth of one chip in GB/s, keyed by ``device_kind``.
+# "TPU v5 lite" is the TPU v5e: 819 GB/s (Google Cloud, "TPU v5e").
+# benchmarks/roofline.py HBM_BW mirrors the v5e entry — asserted equal in
 # tests/test_obs.py so the two can't drift apart silently.
-PEAK_HBM_GBS = 819.0
+PEAK_HBM_GBS = {"TPU v5 lite": 819.0}
 
 
-def annotate_span(span_dict: dict) -> None:
+def device_kind() -> str:
+    """``device_kind`` of the process's first JAX device."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def annotate_span(span_dict: dict, peak_gbs: float | None) -> None:
     """Recursively annotate ``kernel:*`` spans that carry
-    ``bytes_streamed`` with achieved_gbs + roofline_frac, in place."""
+    ``bytes_streamed`` with achieved_gbs, and with roofline_frac when
+    the chip's peak is known, in place."""
     counters = span_dict.get("counters")
     if (span_dict.get("name", "").startswith("kernel:") and counters
             and counters.get("bytes_streamed")
             and span_dict.get("wall_ms", 0) > 0):
         gbs = counters["bytes_streamed"] / (span_dict["wall_ms"] / 1e3) / 1e9
         counters["achieved_gbs"] = round(gbs, 4)
-        counters["roofline_frac"] = round(gbs / PEAK_HBM_GBS, 6)
+        if peak_gbs is not None:
+            counters["roofline_frac"] = round(gbs / peak_gbs, 6)
     for child in span_dict.get("children", ()):
-        annotate_span(child)
+        annotate_span(child, peak_gbs)
 
 
 def _fold(span_dict: dict, pred) -> float:
@@ -50,14 +60,16 @@ def _fold(span_dict: dict, pred) -> float:
     return total
 
 
-def annotate_costs(trace_dict: dict) -> dict:
+def annotate_costs(trace_dict: dict, kind: str | None = None) -> dict:
     """Annotate a serialized trace (``Trace.to_dict()`` shape) with
     per-kernel roofline numbers and a trace-level ``cost`` verdict.
-    Mutates and returns ``trace_dict``."""
+    ``kind`` is the ``device_kind`` the trace ran on (default: this
+    process's device). Mutates and returns ``trace_dict``."""
     root = trace_dict.get("spans")
     if not root:
         return trace_dict
-    annotate_span(root)
+    peak = PEAK_HBM_GBS.get(kind or device_kind())
+    annotate_span(root, peak)
     wall = trace_dict.get("wall_ms") or root.get("wall_ms", 0.0)
     # kernel spans never nest inside each other, so the fold is a sum of
     # disjoint intervals; queue_wait_ms is a root counter the batcher
@@ -85,7 +97,8 @@ def annotate_costs(trace_dict: dict) -> dict:
         "kernel_ms": round(kernel_ms, 3),
         "queue_wait_ms": round(queue_ms, 3),
         "kernel_frac": round(kernel_ms / wall, 4) if wall > 0 else 0.0,
-        "best_roofline_frac": round(best_frac, 6),
         "bound": bound,
     }
+    if peak is not None:
+        trace_dict["cost"]["best_roofline_frac"] = round(best_frac, 6)
     return trace_dict

@@ -424,7 +424,7 @@ def golden_fixture() -> tuple[str, str]:
             }],
         },
     }
-    annotate_costs(trace_dict)
+    annotate_costs(trace_dict, kind="TPU v5 lite")
     otlp = json.dumps(trace_to_otlp(trace_dict), indent=1,
                       sort_keys=True) + "\n"
     return prom, otlp

@@ -405,11 +405,10 @@ def device_fanout_topk(queries: np.ndarray, emb_stack: np.ndarray,
             emb_local, mask_local)
 
     if mesh is not None:
-        from ..launch.compat import shard_map
         from ..launch.sharding import fabric_fanout_specs
         q_spec, emb_spec, mask_spec, out_specs = fabric_fanout_specs(
             mesh, int(emb.shape[0]))
-        fanned = shard_map(local, mesh=mesh,
+        fanned = jax.shard_map(local, mesh=mesh,
                            in_specs=(q_spec, emb_spec, mask_spec),
                            out_specs=out_specs, check_vma=False)
         s, i = fanned(q, emb, mask)

@@ -26,8 +26,9 @@ def test_sharded_moe_matches_dense_ref():
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.moe import (MoEConfig, moe_params,
                                       moe_block_sharded, moe_block_dense_ref)
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = MoEConfig(n_experts=8, top_k=2, d_ff=16, n_shared=1,
                         capacity_factor=16.0)   # drop-free
         d = 32
@@ -50,8 +51,9 @@ def test_sharded_moe_grads_finite():
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.moe import (MoEConfig, moe_params,
                                       moe_block_sharded)
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = MoEConfig(n_experts=8, top_k=2, d_ff=16, capacity_factor=4.0)
         params = moe_params(jax.random.PRNGKey(0), 32, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
@@ -78,8 +80,9 @@ def test_lm_train_step_sharded_runs():
         from repro.launch.steps import build_cell, make_smoke_args
         from repro.launch import sharding as shd
         from jax.sharding import PartitionSpec as P
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         b = build_cell("qwen2-moe-a2.7b", "train_4k", reduced=True)
         args = make_smoke_args(b)
         in_sh = jax.tree.map(lambda s: shd.named(mesh, s),
@@ -109,8 +112,9 @@ def test_elastic_checkpoint_across_device_counts():
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.train.checkpoint import CheckpointManager
-            from repro.launch.compat import make_mesh
-            mesh = make_mesh((2, 4), ("data", "model"))
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
             w = jnp.arange(64.0).reshape(8, 8)
             w = jax.device_put(w, NamedSharding(mesh, P("data", "model")))
             CheckpointManager({root!r}).save(5, {{"w": w}})
@@ -136,8 +140,9 @@ def test_retrieval_shard_map_matches_local():
         from repro.launch import sharding as shd
         from jax.sharding import PartitionSpec as P
         from repro.kernels.topk_search.ref import topk_search_ref
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         b = build_cell("fm", "retrieval_cand", reduced=True)
         rng = np.random.default_rng(0)
         n, d = b.arg_specs[0]["candidates"].shape
@@ -167,8 +172,9 @@ def test_gqa_decode_sequence_sharded_matches_ref():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.kernels.flash_decode.ref import decode_attention_ref
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((8,), ("model",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("model",),
+                             axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         b, h, kv, s, dh = 2, 8, 2, 64, 16
         q = jnp.asarray(rng.standard_normal((b, h, dh)).astype(np.float32))

@@ -196,4 +196,5 @@ class TestGoldenFiles:
         assert attrs["achieved_gbs"]["doubleValue"] == \
             pytest.approx(1.0486, rel=1e-3)
         assert attrs["roofline_frac"]["doubleValue"] == \
-            pytest.approx(1.0486 / obs.PEAK_HBM_GBS, rel=1e-3)
+            pytest.approx(1.0486 / obs.PEAK_HBM_GBS["TPU v5 lite"],
+                          rel=1e-3)

@@ -128,7 +128,8 @@ class TestCostAnnotation:
                  counters={"bytes_streamed": 8_388_608}))
         return tr
 
-    def test_retained_records_carry_roofline_numbers(self):
+    def test_retained_records_carry_roofline_numbers(self, monkeypatch):
+        monkeypatch.setattr(obs.cost, "device_kind", lambda: "TPU v5 lite")
         rec = FlightRecorder(capacity=8)
         rec.enabled = True
         rec.observe_trace(self._kernel_trace())
@@ -137,9 +138,22 @@ class TestCostAnnotation:
         # 8 MiB in 9ms ≈ 0.932 GB/s
         assert k["achieved_gbs"] == pytest.approx(0.932, rel=0.01)
         assert k["roofline_frac"] == pytest.approx(
-            k["achieved_gbs"] / obs.PEAK_HBM_GBS, rel=1e-3)
+            k["achieved_gbs"] / obs.PEAK_HBM_GBS["TPU v5 lite"], rel=1e-3)
+        assert r["cost"]["best_roofline_frac"] == k["roofline_frac"]
         assert r["cost"]["bound"] == "bandwidth-bound"
         assert r["cost"]["kernel_frac"] == pytest.approx(0.9, rel=0.01)
+
+    def test_unknown_device_kind_gets_no_roofline(self, monkeypatch):
+        monkeypatch.setattr(obs.cost, "device_kind", lambda: "cpu")
+        rec = FlightRecorder(capacity=8)
+        rec.enabled = True
+        rec.observe_trace(self._kernel_trace())
+        (r,) = rec.records()
+        k = r["spans"]["children"][0]["counters"]
+        assert k["achieved_gbs"] == pytest.approx(0.932, rel=0.01)
+        assert "roofline_frac" not in k
+        assert "best_roofline_frac" not in r["cost"]
+        assert r["cost"]["bound"] == "bandwidth-bound"
 
     def test_bound_verdicts(self):
         rec = FlightRecorder(capacity=8)

@@ -728,4 +728,4 @@ class TestRooflineConstant:
         # src must not import from benchmarks/, so obs/cost.py
         # duplicates the constant — this is the drift guard
         from benchmarks.roofline import HBM_BW
-        assert obs.PEAK_HBM_GBS * 1e9 == HBM_BW
+        assert obs.PEAK_HBM_GBS["TPU v5 lite"] * 1e9 == HBM_BW

@@ -151,6 +151,24 @@ class TestRecallRegression:
         assert hits / 250 >= 0.95
         assert idx.stats()["avg_fraction_scanned"] < 0.5
 
+    def test_merged_segment_probes_an_eighth_of_its_partitions(self):
+        """A 16k-row segment has 128 partitions: nprobe=8 alone would
+        scan 1/16 of it. The segment probes at least 1/8, so a merged
+        segment keeps the recall of a memtable-sized one."""
+        from repro.index.segment import Segment
+        rng = np.random.default_rng(4)
+        n, d = 16_384, 16
+        emb = rng.standard_normal((n, d)).astype(np.float32)
+        emb /= np.linalg.norm(emb, axis=1, keepdims=True)
+        seg = Segment("s", emb, np.ones(n, np.int64), np.arange(n),
+                      [f"c{i}" for i in range(n)], ["d"] * n, [""] * n)
+        assert seg.ivf.centroids.shape[0] == 128
+        q = emb[:16]
+        _, _, scanned = seg.search(q, k=10, nprobe=8)
+        assert scanned >= n // 8 * 0.5       # partitions are uneven
+        _, _, scanned_16 = seg.search(q, k=10, nprobe=16)
+        assert scanned == scanned_16
+
     def test_ivf_state_roundtrips_without_kmeans(self, tmp_path, monkeypatch):
         """Segment save/load must reuse the persisted partitioning: same
         search results, and IVFIndex.build (k-means) never runs on load."""
