@@ -359,7 +359,7 @@ def rows_from(result: dict) -> list[tuple]:
                      worst.get("wall_ms", 0.0),
                      f"reason={worst.get('reason')}, "
                      f"bound={cost.get('bound')}, "
-                     f"kernel_frac={cost.get('kernel_frac')}"))
+                     f"device_wait_frac={cost.get('device_wait_frac')}"))
     rows.append(("load_slo/degraded/recall_at10", d["recall_at10"],
                  f"shard {d['dead_shard']} down, R=2, "
                  f"marked={'yes' if d['marked_degraded'] else 'NO'}"))

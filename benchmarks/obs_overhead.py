@@ -10,7 +10,8 @@ fused-block dispatch, the hottest instrumented path) in three modes:
   - noop:     no trace active — the production default; instrumented
               code exercises only the no-op guards;
   - traced:   every search runs under an active trace, so each dispatch
-              records real spans (fused_scan + kernel:topk_search);
+              records real spans (fused_scan, kernel:topk_search, h2d,
+              device_wait), each also a jax.profiler annotation;
   - recorded: traced AND the full §15 judgment layer is on — a tenant
               SLO declared (every finished trace feeds burn-rate
               accounting) and the flight recorder enabled (every

@@ -411,10 +411,8 @@ def main(argv=None) -> int:
         say(f"compile cache: {enable_compile_cache()}")
         compiles = CompileCounter()
         device = device_check()
-        from repro.obs.cost import PEAK_HBM_GBS
         say(f"device: {device['kind']} x {device['count']} "
-            f"({device['platform']}); HBM peak in the cost table: "
-            f"{PEAK_HBM_GBS.get(device['kind'], 'none')}")
+            f"({device['platform']})")
         smoke(args.seed)
         stats = jax.devices()[0].memory_stats() or {}
         say(f"compiles: {compiles.n} backend compiles, "

@@ -471,6 +471,7 @@ class TemporalEngine:
             if visible is not None:
                 vis = visible_rows(res.tids[:res.n], visible)
                 vf = np.where(vis, vf, VALID_TO_OPEN)
+            from ..kernels.common import to_host
             if res.quantized:
                 from ..index.quant import pool_k, rescore_topk
                 from ..kernels.temporal_mask_score.ops import (
@@ -479,13 +480,14 @@ class TemporalEngine:
                 sp.add("rescore_pool", int(kp) * nq)
                 _, pool = temporal_window_topk_q8(qp, emb, res.scale,
                                                   vf, vt, t0s, t1s, kp)
-                scores, idx = rescore_topk(qp[:nq], np.asarray(pool)[:nq],
+                (pool,) = to_host(pool)
+                scores, idx = rescore_topk(qp[:nq], pool[:nq],
                                            res.fetch_f32, k)
             else:
                 from ..kernels.temporal_mask_score.ops import (
                     temporal_window_topk)
-                scores, idx = temporal_window_topk(qp, emb, vf, vt,
-                                                   t0s, t1s, k)
+                scores, idx = to_host(*temporal_window_topk(
+                    qp, emb, vf, vt, t0s, t1s, k))
             # the fused temporal block reads the whole resident history
             # once per BATCH, same convention as the hot fused scan
             obs.scan_row_reads(

@@ -707,6 +707,7 @@ class SegmentedIndex:
         fmask = auth[cat.fused_gids]
         if fmask.any():
             with obs.span("fused_scan") as fsp:
+                from ..kernels.common import to_host
                 qp, _ = pad_queries(q)
                 k_eff = min(k, cat.fused_emb.shape[0])
                 if self.quantized:
@@ -716,14 +717,15 @@ class SegmentedIndex:
                     _, pool = topk_search_q8(qp, cat.fused_emb,
                                              fixed_scale(self.dim),
                                              fmask, kp)
+                    (pool,) = to_host(pool)
                     fsp.add("rescore_pool", int(kp) * nq)
-                    s, idx = rescore_topk(q, np.asarray(pool)[:nq],
-                                          cat.fused_f32, k_eff)
+                    s, idx = rescore_topk(q, pool[:nq], cat.fused_f32,
+                                          k_eff)
                 else:
                     from ..kernels.topk_search.ops import topk_search
-                    s, idx = topk_search(qp, cat.fused_emb, fmask, k_eff)
-                    s = np.asarray(s)[:nq]
-                    idx = np.asarray(idx)[:nq]
+                    s, idx = to_host(*topk_search(qp, cat.fused_emb,
+                                                  fmask, k_eff))
+                    s, idx = s[:nq], idx[:nq]
                 g = np.where(np.isfinite(s),
                              cat.fused_gids[np.clip(idx, 0, None)], -1)
                 blocks_s.append(np.asarray(s, np.float32))
@@ -759,7 +761,7 @@ class SegmentedIndex:
                     else vis[sbase:sbase + len(seg)])
             if seg.n_alive == 0 or (svis is not None and not svis.any()):
                 continue
-            with obs.span(f"ivf_scan:{seg.seg_id}") as isp:
+            with obs.span(f"ivf_scan:{seg.seg_id}"):
                 s, rows, seg_scanned = seg.search(q, k,
                                                   nprobe=self.nprobe,
                                                   visible=svis)
@@ -769,14 +771,10 @@ class SegmentedIndex:
                              -1)
                 blocks_s.append(s)
                 blocks_g.append(g)
-                # per-query avg x queries (host-side member gathers, so
-                # bytes are accounted here — no kernel span underneath)
-                reads = obs.scan_row_reads(
+                # per-query avg x queries (host-side member gathers)
+                scanned += obs.scan_row_reads(
                     seg_scanned, nq, per_query=True, source="ivf",
                     row_bytes=self.dim * (1 if self.quantized else 4))
-                isp.add("bytes_streamed",
-                        reads * self.dim * (1 if self.quantized else 4))
-                scanned += reads
         self._scan_scanned += scanned
         self._scan_denom += max(len(self._by_key), 1) * nq
         if not blocks_s:

@@ -41,6 +41,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from .. import obs
+
 Q8_MAX = 127
 _SCALE_FLOOR = 1e-12
 
@@ -140,7 +142,10 @@ def rescore_topk(q: np.ndarray, pool_idx: np.ndarray,
     rank — stable). Empty slots come back (-inf, -1).
 
     Cost: one fetch of the UNIQUE pool rows across the whole batch plus
-    one (Q, U) matmul with U <= Q*k' — independent of corpus size.
+    one (Q, U) matmul with U <= Q*k' — independent of corpus size. Every
+    caller's rescore runs under one ``rescore`` span, counting the unique
+    rows fetched (``rescore_rows``) and their fp32 bytes
+    (``rescore_bytes``).
     """
     q = np.atleast_2d(np.asarray(q, np.float32))
     pool_idx = np.atleast_2d(np.asarray(pool_idx, np.int64))
@@ -149,26 +154,30 @@ def rescore_topk(q: np.ndarray, pool_idx: np.ndarray,
     if k == 0:
         return (np.full((nq, 0), -np.inf, np.float32),
                 np.full((nq, 0), -1, np.int64))
-    uniq, inv = np.unique(np.clip(pool_idx, 0, None), return_inverse=True)
-    if isinstance(f32_rows, F32Rows):
-        rows = f32_rows.get(uniq)
-    elif callable(f32_rows):
-        rows = np.asarray(f32_rows(uniq), np.float32)
-    else:
-        rows = np.asarray(f32_rows, np.float32)[uniq]
-    # einsum, NOT @: the pool is tiny, and a threaded BLAS gemm here
-    # would leave OpenBLAS worker threads spinning right when the next
-    # int8 GEMM (torch/oneDNN pool) wants the cores — that ping-pong
-    # measured ~9x on the raw GEMM and ~3x on the end-to-end scan on a
-    # 2-core host
-    exact = np.einsum("qd,ud->qu", q, rows)               # (Q, U)
-    s = np.take_along_axis(exact, inv.reshape(nq, kp), axis=1)
-    s = np.where(pool_idx >= 0, s, -np.inf).astype(np.float32)
-    order = np.argsort(-s, axis=1, kind="stable")[:, :k]
-    top_s = np.take_along_axis(s, order, axis=1)
-    top_i = np.where(np.isfinite(top_s),
-                     np.take_along_axis(pool_idx, order, axis=1), -1)
-    return top_s, top_i
+    with obs.span("rescore") as sp:
+        uniq, inv = np.unique(np.clip(pool_idx, 0, None),
+                              return_inverse=True)
+        sp.add("rescore_rows", len(uniq))
+        sp.add("rescore_bytes", len(uniq) * q.shape[1] * 4)
+        if isinstance(f32_rows, F32Rows):
+            rows = f32_rows.get(uniq)
+        elif callable(f32_rows):
+            rows = np.asarray(f32_rows(uniq), np.float32)
+        else:
+            rows = np.asarray(f32_rows, np.float32)[uniq]
+        # einsum, NOT @: the pool is tiny, and a threaded BLAS gemm
+        # here would leave OpenBLAS worker threads spinning right when
+        # the next int8 GEMM (torch/oneDNN pool) wants the cores — that
+        # ping-pong measured ~9x on the raw GEMM and ~3x on the
+        # end-to-end scan on a 2-core host
+        exact = np.einsum("qd,ud->qu", q, rows)           # (Q, U)
+        s = np.take_along_axis(exact, inv.reshape(nq, kp), axis=1)
+        s = np.where(pool_idx >= 0, s, -np.inf).astype(np.float32)
+        order = np.argsort(-s, axis=1, kind="stable")[:, :k]
+        top_s = np.take_along_axis(s, order, axis=1)
+        top_i = np.where(np.isfinite(top_s),
+                         np.take_along_axis(pool_idx, order, axis=1), -1)
+        return top_s, top_i
 
 
 # ---------------------------------------------------------------------------

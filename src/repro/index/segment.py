@@ -249,17 +249,18 @@ class Segment:
                                           mask=mask)
             return s, i, int(round(stats.fraction_scanned * len(self)))
         from ..core.types import pad_queries
+        from ..kernels.common import to_host
         qp, _ = pad_queries(q)
         if self.quantized:
             from ..kernels.topk_search.ops import topk_search_q8
             kp = pool_k(k_eff, len(self), self.rescore_factor)
             _, pool = topk_search_q8(qp, self.q8, self.scale, mask, kp)
-            s, i = rescore_topk(q, np.asarray(pool)[:nq], self.fetch_f32,
-                                k_eff)
+            (pool,) = to_host(pool)
+            s, i = rescore_topk(q, pool[:nq], self.fetch_f32, k_eff)
             return s, i, n_mask
         from ..kernels.topk_search.ops import topk_search
-        s, i = topk_search(qp, self.emb, mask, k_eff)
-        return np.asarray(s)[:nq], np.asarray(i)[:nq], n_mask
+        s, i = to_host(*topk_search(qp, self.emb, mask, k_eff))
+        return s[:nq], i[:nq], n_mask
 
     # -- persistence -------------------------------------------------------
     def filename(self) -> str:

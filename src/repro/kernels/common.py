@@ -16,6 +16,9 @@ from __future__ import annotations
 import os
 
 import jax
+import numpy as np
+
+from .. import obs
 
 
 def kernel_mode(override: str | None = None) -> str:
@@ -40,6 +43,33 @@ def kernel_mode_q8(override: str | None = None) -> str:
     if mode not in ("pallas", "interpret", "ref", "host"):
         raise ValueError(f"bad kernel mode {mode!r}")
     return mode
+
+
+def to_device(*args):
+    """``(array, dtype)`` pairs as device arrays, converted under an
+    ``h2d`` span whose counter ``h2d_bytes`` sums the nbytes of the host
+    arrays handed to the device. An argument that is already a device
+    array (or a tracer) is converted there and counts nothing."""
+    import jax.numpy as jnp
+    with obs.span("h2d") as sp:
+        out = []
+        for x, dtype in args:
+            if isinstance(x, jax.Array):
+                out.append(jnp.asarray(x, dtype))
+                continue
+            x = np.asarray(x, dtype)
+            sp.add("h2d_bytes", int(x.nbytes))
+            out.append(jnp.asarray(x))
+        return out
+
+
+def to_host(*outs) -> tuple:
+    """A kernel's outputs as host arrays, under a ``device_wait`` span:
+    an explicit block on the device, then the copy back. The span is the
+    time the host waits on the device and on the results' return."""
+    with obs.span("device_wait"):
+        jax.block_until_ready(outs)
+        return tuple(np.asarray(o) for o in outs)
 
 
 def pad_to(x, axis: int, multiple: int, value=0):
@@ -105,7 +135,6 @@ def split_i64(x):
     """Split non-negative int64 (numpy, host-side) into (hi:int32,
     lo:uint32) device arrays — TPUs are 32-bit machines and JAX x64 is off;
     lexicographic compare on (hi, lo) is exact for timestamps."""
-    import numpy as np
     x = np.asarray(x, np.int64)
     hi = (x >> 32).astype(np.int32)
     lo = (x & np.int64(0xFFFFFFFF)).astype(np.uint32)

@@ -17,7 +17,7 @@ import numpy as np
 
 from ... import obs
 from ..common import (kernel_mode, kernel_mode_q8, merge_blocks, pad_to,
-                      row_block, split_i64)
+                      row_block, split_i64, to_device)
 from .ref import temporal_window_topk_q8_ref, temporal_window_topk_ref
 from .temporal_mask_score import temporal_block_candidates
 
@@ -36,8 +36,9 @@ def _split_flip(x_i64: np.ndarray) -> np.ndarray:
     return np.stack([hi, (lo ^ np.uint32(1 << 31)).view(np.int32)])
 
 
-def _device_words(valid_from, valid_to, t0s, t1s, bn: int):
-    """Validity words (4, N padded to bn) and window words (Q, 4)."""
+def _host_words(valid_from, valid_to, t0s, t1s, bn: int):
+    """Validity words (4, N padded to bn) and window words (Q, 4), on
+    the host."""
     pad = (-len(valid_from)) % bn
     vf = np.concatenate([np.asarray(valid_from, np.int64),
                          np.full(pad, _PAD_FROM, np.int64)])
@@ -45,7 +46,7 @@ def _device_words(valid_from, valid_to, t0s, t1s, bn: int):
                          np.full(pad, _PAD_TO, np.int64)])
     valid = np.concatenate([_split_flip(vf), _split_flip(vt)])
     win = np.concatenate([_split_flip(t0s), _split_flip(t1s)]).T
-    return jnp.asarray(valid), jnp.asarray(np.ascontiguousarray(win))
+    return valid, np.ascontiguousarray(win)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bn", "interpret", "q8"))
@@ -73,7 +74,7 @@ def temporal_window_topk(q, corpus, valid_from, valid_to, t0s, t1s, k: int,
     overlapping candidate come back -inf.
     """
     mode = kernel_mode(mode)
-    with obs.span("kernel:temporal_window_topk") as sp:
+    with obs.span("kernel:temporal_window_topk"):
         q = np.atleast_2d(np.asarray(q, np.float32))
         t0s = np.broadcast_to(np.asarray(t0s, np.int64), (q.shape[0],))
         t1s = np.broadcast_to(np.asarray(t1s, np.int64), (q.shape[0],))
@@ -82,17 +83,16 @@ def temporal_window_topk(q, corpus, valid_from, valid_to, t0s, t1s, k: int,
             # empty history: nothing can ever be valid, regardless of window
             return (np.zeros((q.shape[0], 0), np.float32),
                     np.zeros((q.shape[0], 0), np.int32))
-        sp.add("rows", int(corpus.shape[0]))
-        sp.add("bytes_streamed",
-               int(corpus.shape[0]) * int(corpus.shape[1]) * 4)
         if mode == "ref":
             return temporal_window_topk_ref(q, corpus, valid_from,
                                             valid_to, t0s, t1s, k)
         bn = row_block(int(corpus.shape[0]), bn)
-        valid, win = _device_words(valid_from, valid_to, t0s, t1s, bn)
-        return _temporal_topk_jit(
-            jnp.asarray(q), jnp.asarray(corpus, jnp.float32), valid, win,
-            k, bn, mode == "interpret", False)
+        valid, win = _host_words(valid_from, valid_to, t0s, t1s, bn)
+        q, corpus, valid, win = to_device(
+            (q, np.float32), (corpus, np.float32), (valid, None),
+            (win, None))
+        return _temporal_topk_jit(q, corpus, valid, win,
+                                  k, bn, mode == "interpret", False)
 
 
 def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
@@ -108,7 +108,7 @@ def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
     filter runs before ranking in EVERY mode, so the leakage guarantee
     is identical to the fp32 path."""
     mode = kernel_mode_q8(mode)
-    with obs.span("kernel:temporal_window_topk_q8") as sp:
+    with obs.span("kernel:temporal_window_topk_q8"):
         q = np.atleast_2d(np.asarray(q, np.float32))
         c8 = np.asarray(c8, np.int8)
         scale = np.asarray(scale, np.float32)
@@ -118,8 +118,6 @@ def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
         if c8.shape[0] == 0 or k == 0:
             return (np.zeros((q.shape[0], 0), np.float32),
                     np.zeros((q.shape[0], 0), np.int32))
-        sp.add("rows", int(c8.shape[0]))
-        sp.add("bytes_streamed", int(c8.shape[0]) * int(c8.shape[1]))
         from ...index.quant import fold_scale
         qs = fold_scale(q, scale)
         vf = np.asarray(valid_from, np.int64)
@@ -135,10 +133,11 @@ def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
             scores[~valid] = -np.inf
             return pool_topk_host(scores, k)
         bn = row_block(int(c8.shape[0]), bn)
-        valid, win = _device_words(vf, vt, t0s, t1s, bn)
-        return _temporal_topk_jit(
-            jnp.asarray(qs), jnp.asarray(c8), valid, win,
-            k, bn, mode == "interpret", True)
+        valid, win = _host_words(vf, vt, t0s, t1s, bn)
+        qs, c8, valid, win = to_device(
+            (qs, np.float32), (c8, np.int8), (valid, None), (win, None))
+        return _temporal_topk_jit(qs, c8, valid, win,
+                                  k, bn, mode == "interpret", True)
 
 
 def temporal_topk(q, corpus, valid_from, valid_to, ts: int, k: int,

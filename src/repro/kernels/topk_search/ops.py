@@ -10,7 +10,7 @@ import numpy as np
 
 from ... import obs
 from ..common import (kernel_mode, kernel_mode_q8, merge_blocks, pad_to,
-                      row_block)
+                      row_block, to_device)
 from .ref import topk_search_q8_ref, topk_search_ref
 from .topk_search import topk_block_candidates
 
@@ -40,15 +40,15 @@ def topk_search(q, corpus, mask, k: int, bn: int = 512,
     q: (Q, D) or (D,); corpus: (N, D); mask: (N,) bool. Returns
     (scores (Q, k), idx (Q, k)). Rows with mask=False can never appear
     unless fewer than k rows are active (callers drop -inf entries).
+    The outputs are device arrays: the span holds the argument copy and
+    the enqueue, and the caller waits for them (``common.to_host``).
     """
-    with obs.span("kernel:topk_search") as sp:
-        q = jnp.atleast_2d(jnp.asarray(q, jnp.float32))
-        corpus = jnp.asarray(corpus, jnp.float32)
-        mask = jnp.asarray(mask, bool)
+    with obs.span("kernel:topk_search"):
+        q, corpus, mask = to_device((q, np.float32), (corpus, np.float32),
+                                    (mask, bool))
+        q = jnp.atleast_2d(q)
         k = int(min(k, corpus.shape[0]))
         bn = row_block(int(corpus.shape[0]), bn)
-        sp.add("rows", int(corpus.shape[0]))
-        sp.add("bytes_streamed", int(corpus.shape[0]) * int(corpus.shape[1]) * 4)
         return _topk_search_jit(q, corpus, mask, k, bn, kernel_mode(mode))
 
 
@@ -81,7 +81,7 @@ def topk_search_q8(q, c8, scale, mask, k: int, bn: int = 512,
     pure-jnp oracle; host = CPU integer-GEMM scan (kernels/qscan, auto
     default off-TPU)."""
     mode = kernel_mode_q8(mode)
-    with obs.span("kernel:topk_search_q8") as sp:
+    with obs.span("kernel:topk_search_q8"):
         q = np.atleast_2d(np.asarray(q, np.float32))
         c8 = np.asarray(c8, np.int8)
         scale = np.asarray(scale, np.float32)
@@ -89,8 +89,6 @@ def topk_search_q8(q, c8, scale, mask, k: int, bn: int = 512,
         if c8.shape[0] == 0 or k == 0:
             return (np.zeros((q.shape[0], 0), np.float32),
                     np.zeros((q.shape[0], 0), np.int32))
-        sp.add("rows", int(c8.shape[0]))
-        sp.add("bytes_streamed", int(c8.shape[0]) * int(c8.shape[1]))
         from ...index.quant import fold_scale
         qs = fold_scale(q, scale)
         if mode == "host":
@@ -99,5 +97,6 @@ def topk_search_q8(q, c8, scale, mask, k: int, bn: int = 512,
             scores[:, ~np.asarray(mask, bool)] = -np.inf
             return pool_topk_host(scores, k)
         bn = row_block(int(c8.shape[0]), bn)
-        return _topk_search_q8_jit(jnp.asarray(qs), jnp.asarray(c8),
-                                   jnp.asarray(mask, bool), k, bn, mode)
+        qs, c8, mask = to_device((qs, np.float32), (c8, np.int8),
+                                 (mask, bool))
+        return _topk_search_q8_jit(qs, c8, mask, k, bn, mode)

@@ -390,9 +390,42 @@ class ObsHttpServer:
 # Golden fixture + CLI (CI bench-smoke runs this without pytest)
 # ---------------------------------------------------------------------
 
+def golden_trace() -> dict:
+    """The golden fixture's trace: a fused scan whose host mostly waits
+    on the device, with its ``cost`` verdict."""
+    return annotate_costs({
+        "name": "batch", "intent": "current", "wall_ms": 12.5,
+        "attrs": {"tenant": "acme"},
+        "spans": {
+            "name": "batch", "wall_ms": 12.5,
+            "counters": {"queue_wait_ms": 1.5, "batch_size": 8},
+            "children": [{
+                "name": "plan", "wall_ms": 10.0,
+                "children": [{
+                    "name": "shard:s00", "wall_ms": 9.0,
+                    "children": [{
+                        "name": "fused_scan", "wall_ms": 8.5,
+                        "children": [{
+                            "name": "kernel:topk_search_q8",
+                            "wall_ms": 1.5,
+                            "children": [{
+                                "name": "h2d", "wall_ms": 1.0,
+                                "counters": {"h2d_bytes": 8388608},
+                            }],
+                        }, {
+                            "name": "device_wait", "wall_ms": 7.0,
+                        }],
+                    }],
+                }],
+            }],
+        },
+    })
+
+
 def golden_fixture() -> tuple[str, str]:
     """A fixed registry + trace rendered to both formats — the golden
-    files lock the exposition format AND the cost-attribution math."""
+    files lock the exposition format AND the cost verdict (the trace id
+    digests the whole annotated trace)."""
     reg = MetricsRegistry()
     reg.counter("scan_row_reads", source="fused").inc(4096)
     reg.counter("scan_row_reads", tenant="acme").inc(4096)
@@ -405,27 +438,7 @@ def golden_fixture() -> tuple[str, str]:
         h.observe(v)
     prom = prometheus_text(reg)
 
-    trace_dict = {
-        "name": "batch", "intent": "current", "wall_ms": 12.5,
-        "attrs": {"tenant": "acme"},
-        "spans": {
-            "name": "batch", "wall_ms": 12.5,
-            "counters": {"queue_wait_ms": 1.5, "batch_size": 8},
-            "children": [{
-                "name": "plan", "wall_ms": 10.0,
-                "children": [{
-                    "name": "shard:s00", "wall_ms": 9.0,
-                    "children": [{
-                        "name": "kernel:topk_search_q8", "wall_ms": 8.0,
-                        "counters": {"rows": 65536,
-                                     "bytes_streamed": 8388608},
-                    }],
-                }],
-            }],
-        },
-    }
-    annotate_costs(trace_dict, kind="TPU v5 lite")
-    otlp = json.dumps(trace_to_otlp(trace_dict), indent=1,
+    otlp = json.dumps(trace_to_otlp(golden_trace()), indent=1,
                       sort_keys=True) + "\n"
     return prom, otlp
 

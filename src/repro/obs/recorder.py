@@ -24,9 +24,10 @@ interesting record — the invariant tests assert: an error trace is
 never evicted while a sampled-ok trace remains.
 
 Retained traces are stored SERIALIZED (plain dicts via
-``Trace.to_dict()``) and cost-annotated (obs/cost.py) at retention
-time, so holding a record never pins live index state and a dumped
-trace self-explains as bandwidth/dispatch/queue-bound.
+``Trace.to_dict()``), so holding a record never pins live index state.
+They are cost-annotated (obs/cost.py) when read or dumped — off the
+lock and off the serving thread — so a dumped trace self-explains as
+device-, host- or queue-bound.
 
 Autodump: ``enable()`` registers a listener on the fault registry
 (testing/faults.py); every injected fault triggers an immediate
@@ -145,7 +146,7 @@ class FlightRecorder:
                 self._dump_due = None
             else:
                 self._seq += 1
-                rec = annotate_costs(tr.to_dict())
+                rec = tr.to_dict()
                 rec["seq"] = self._seq
                 rec["kind"] = "trace"
                 rec["reason"] = reason or "sampled"
@@ -181,10 +182,13 @@ class FlightRecorder:
 
     # -- reading --------------------------------------------------------
     def records(self) -> list[dict]:
-        """Everything currently retained, in completion order."""
+        """Everything currently retained, in completion order; each
+        trace record a copy carrying its ``cost`` verdict."""
         with self._lock:
             out = list(self._keep) + list(self._sampled)
-        return sorted(out, key=lambda r: r["seq"])
+        out.sort(key=lambda r: r["seq"])
+        return [annotate_costs(dict(r)) if r["kind"] == "trace" else r
+                for r in out]
 
     def summary(self) -> dict:
         with self._lock:

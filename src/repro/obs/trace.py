@@ -5,14 +5,24 @@ through the stack by a contextvar — the batcher opens the trace, and
 every layer underneath (planner scatter, per-shard engine pass, index
 scan, kernel dispatch) attaches nested spans WITHOUT any plumbing
 through call signatures. A span records wall time plus a small dict of
-numeric counters (rows_scanned, bytes_streamed, segments_pruned,
-candidates, rescore_pool, ...).
+numeric counters (rows_scanned, h2d_bytes, rescore_rows,
+segments_pruned, candidates, rescore_pool, ...).
 
 The no-op fast path is the design center: when no trace is active (or
 tracing is globally disabled), ``span()``/``add()`` return a shared
-singleton / return immediately — no allocation, no clock read. The
-overhead of tracing-enabled vs no-op mode is measured and gated <2% on
-the fused-scan benchmark (benchmarks/obs_overhead.py, CI bench-smoke).
+singleton / return immediately — no allocation, no clock read, no
+profiler annotation. ``benchmarks/obs_overhead.py`` (a CI bench-smoke
+step, not tier-1) gates the cost of the TRACED mode against the no-op
+mode at <2% on a 16k-row, 384-d fused CPU scan, where one search takes
+milliseconds; the no-op path itself is held to zero allocation by
+tests/test_obs.py.
+
+Profiler clock: while a trace is active, every span (and the trace's
+root) also opens a ``jax.profiler.TraceAnnotation`` under its own name,
+so a ``jax.profiler`` capture holds the program's span tree on the
+device trace's clock, nested as the tree is. ``jax.profiler`` is
+imported on the first enabled span, never at import: ``obs`` stays
+importable without JAX (spans then carry no annotation).
 
 Span taxonomy (stable names — DESIGN.md §12 documents the contract):
 
@@ -25,7 +35,15 @@ Span taxonomy (stable names — DESIGN.md §12 documents the contract):
             fused_scan      memtable + small-segment fused dispatch
             solo_scan / ivf_scan:<seg>   per-segment scans
             fused_temporal  resident full-history temporal dispatch
-            kernel:<name>   one device/host kernel dispatch
+            kernel:<name>   one kernel call: argument copy plus enqueue
+                            (the host's share; device time is in the
+                            profiler trace)
+              h2d           host->device conversion of the arguments
+                            (h2d_bytes: nbytes of the host arrays)
+            device_wait     block on a kernel's outputs and bring them
+                            to the host (device time + device->host)
+            rescore         exact fp32 rescore of an int8 pool
+                            (rescore_rows, rescore_bytes)
       merge                 cross-shard candidate merge
 
 Counters are pure numbers; ``Span.total(name)`` folds a counter over a
@@ -41,6 +59,29 @@ from typing import Optional
 _ACTIVE: ContextVar[Optional["Trace"]] = ContextVar("obs_trace",
                                                     default=None)
 _ENABLED = True
+_PROFILER = None        # jax.profiler once imported; False without JAX
+
+
+def _annotate(name: str):
+    """Open a profiler annotation named ``name`` (entered), or None
+    where JAX is not installed. The class is looked up on every call,
+    so the annotation is always the profiler's current one."""
+    global _PROFILER
+    if _PROFILER is None:
+        try:
+            import jax.profiler as _PROFILER
+        except ImportError:
+            _PROFILER = False
+    if _PROFILER is False:
+        return None
+    ann = _PROFILER.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+def _close(ann, etype, exc, tb) -> None:
+    if ann is not None:
+        ann.__exit__(etype, exc, tb)
 
 
 def set_enabled(on: bool) -> None:
@@ -159,7 +200,7 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("tr", "name", "span", "t0")
+    __slots__ = ("tr", "name", "span", "t0", "ann")
 
     def __init__(self, tr: Trace, name: str):
         self.tr = tr
@@ -170,12 +211,14 @@ class _SpanCtx:
         self.tr.stack[-1].children.append(sp)
         self.tr.stack.append(sp)
         self.span = sp
+        self.ann = _annotate(self.name)
         self.t0 = time.perf_counter()
         return sp
 
     def __exit__(self, etype, exc, tb):
         sp = self.span
         sp.wall_ms = (time.perf_counter() - self.t0) * 1e3
+        _close(self.ann, etype, exc, tb)
         if etype is not None:
             sp.status = f"error:{etype.__name__}"
         self.tr.stack.pop()
@@ -183,7 +226,7 @@ class _SpanCtx:
 
 
 class _TraceCtx:
-    __slots__ = ("name", "intent", "attrs", "tr", "token", "t0")
+    __slots__ = ("name", "intent", "attrs", "tr", "token", "t0", "ann")
 
     def __init__(self, name: str, intent: Optional[str],
                  attrs: Optional[dict] = None):
@@ -194,6 +237,7 @@ class _TraceCtx:
     def __enter__(self) -> Span:
         self.tr = Trace(self.name, self.intent, attrs=self.attrs)
         self.token = _ACTIVE.set(self.tr)
+        self.ann = _annotate(self.name)
         self.t0 = time.perf_counter()
         return self.tr.root
 
@@ -201,6 +245,7 @@ class _TraceCtx:
         tr = self.tr
         tr.wall_ms = tr.root.wall_ms = \
             (time.perf_counter() - self.t0) * 1e3
+        _close(self.ann, etype, exc, tb)
         if etype is not None:
             tr.root.status = f"error:{etype.__name__}"
         _ACTIVE.reset(self.token)
@@ -227,7 +272,7 @@ class _SubtraceCtx:
     but does NOT feed the registry/slow-query log — the dispatching
     thread grafts the finished subtree into its own trace."""
 
-    __slots__ = ("name", "tr", "token", "t0")
+    __slots__ = ("name", "tr", "token", "t0", "ann")
 
     def __init__(self, name: str):
         self.name = name
@@ -235,6 +280,7 @@ class _SubtraceCtx:
     def __enter__(self) -> Span:
         self.tr = Trace(self.name)
         self.token = _ACTIVE.set(self.tr)
+        self.ann = _annotate(self.name)
         self.t0 = time.perf_counter()
         return self.tr.root
 
@@ -242,6 +288,7 @@ class _SubtraceCtx:
         tr = self.tr
         tr.wall_ms = tr.root.wall_ms = \
             (time.perf_counter() - self.t0) * 1e3
+        _close(self.ann, etype, exc, tb)
         if etype is not None:
             tr.root.status = f"error:{etype.__name__}"
         _ACTIVE.reset(self.token)

@@ -12,7 +12,8 @@ import pytest
 
 from repro import obs
 from repro.obs import MetricsRegistry
-from repro.obs.export import (GOLDEN_FILES, ObsHttpServer, golden_fixture,
+from repro.obs.export import (GOLDEN_FILES, ObsHttpServer, _from_otlp_value,
+                              golden_fixture, golden_trace,
                               parse_prometheus_text, prometheus_text,
                               trace_from_otlp, trace_to_otlp)
 
@@ -84,9 +85,11 @@ class TestOtlpRoundTrip:
             root.add("queue_wait_ms", 1.5)
             with obs.span("plan"):
                 with obs.span("shard:s00"):
-                    with obs.span("kernel:topk_search_q8") as k:
-                        k.add("rows", 65536)
-                        k.add("bytes_streamed", 8_388_608)
+                    with obs.span("kernel:topk_search_q8"):
+                        with obs.span("h2d") as h:
+                            h.add("h2d_bytes", 8_388_608)
+                    with obs.span("device_wait"):
+                        pass
                 try:
                     with obs.span("shard:s01"):
                         raise RuntimeError("boom")
@@ -190,11 +193,13 @@ class TestGoldenFiles:
         _, otlp = golden_fixture()
         doc = json.loads(otlp)
         spans = doc["resourceSpans"][0]["scopeSpans"][0]["spans"]
+        (h,) = [s for s in spans if s["name"] == "h2d"]
+        attrs = {a["key"]: a["value"] for a in h["attributes"]}
+        assert _from_otlp_value(attrs["h2d_bytes"]) == 8_388_608
         (k,) = [s for s in spans if s["name"] == "kernel:topk_search_q8"]
-        attrs = {a["key"]: a["value"] for a in k["attributes"]}
-        # 8 MiB in 8 ms = 1.0486 GB/s; fraction of the 819 GB/s roofline
-        assert attrs["achieved_gbs"]["doubleValue"] == \
-            pytest.approx(1.0486, rel=1e-3)
-        assert attrs["roofline_frac"]["doubleValue"] == \
-            pytest.approx(1.0486 / obs.PEAK_HBM_GBS["TPU v5 lite"],
-                          rel=1e-3)
+        assert k["attributes"] == []      # no host-time roofline numbers
+        # 7 ms of the 12.5 ms batch blocked on the device
+        cost = golden_trace()["cost"]
+        assert cost["device_wait_ms"] == 7.0
+        assert cost["device_wait_frac"] == pytest.approx(0.56)
+        assert cost["bound"] == "device-bound"

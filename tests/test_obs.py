@@ -369,12 +369,20 @@ class TestFabricEndToEnd:
             # per-shard subtree totals sum to the planner/root total
             assert sum(per_shard) == plan[0].total("rows_scanned") \
                 == tr.root.total("rows_scanned")
-            # kernel dispatches appear with rows + bytes
+            # kernel dispatches appear; they time the argument copy and
+            # the enqueue, so they carry no scan counters, and the wait
+            # for their outputs is a device_wait sibling
             kernels = tr.root.find_prefix("kernel:")
             assert kernels
-            assert all(sp.counters.get("rows", 0) > 0 for sp in kernels)
-            assert all(sp.counters.get("bytes_streamed", 0) > 0
-                       for sp in kernels)
+            assert not any({"rows", "bytes_streamed"} & sp.counters.keys()
+                           for sp in kernels)
+            fused = tr.root.find("fused_temporal")
+            assert fused
+            for f in fused:
+                names = [c.name for c in f.children]
+                assert names.index("device_wait") > \
+                    max(i for i, n in enumerate(names)
+                        if n.startswith("kernel:"))
             assert tr.root.find("merge")
             # health(): one call returns topology + metrics + slowlog
             h = fab.health()
@@ -725,7 +733,14 @@ class TestTenantMetering:
 
 class TestRooflineConstant:
     def test_cost_peak_mirrors_benchmarks_roofline(self):
-        # src must not import from benchmarks/, so obs/cost.py
-        # duplicates the constant — this is the drift guard
+        # obs keeps no peak (a span times the host, so a roofline share
+        # of it means nothing); the one v5e HBM peak the repo's roofline
+        # figures divide by is the chip benchmark's table, which
+        # benchmarks/roofline.py mirrors — this is the drift guard
+        import json
+        import pathlib
         from benchmarks.roofline import HBM_BW
-        assert obs.PEAK_HBM_GBS["TPU v5 lite"] * 1e9 == HBM_BW
+        peaks = json.loads((pathlib.Path(__file__).parents[1] / "bench"
+                            / "peaks.json").read_text())
+        assert peaks["chips"]["TPU v5 lite"]["hbm_gbs"] * 1e9 == HBM_BW
+        assert not hasattr(obs, "PEAK_HBM_GBS")
