@@ -3,10 +3,17 @@ larger-than-exact-scan corpora (DESIGN.md §2 — ScaNN/TPU-KNN style).
 
 k-means centroids partition the corpus; a query scores all centroids
 (tiny matmul), visits the ``nprobe`` nearest partitions, and runs the
-exact fused top-k only inside them. Recall is controlled by nprobe
-(nprobe == n_centroids -> exact). Centroid assignment and scan both run
-as dense MXU matmuls — no pointer chasing, static shapes, shardable by
-partition.
+exact top-k only inside them. Recall is controlled by nprobe
+(nprobe == n_centroids -> exact).
+
+Where the member scan runs (DESIGN.md §11): on a TPU the segment's scan
+rows stay on the device (``resident``) and ``search_resident`` scores a
+batch against several segments in one dispatch — a dense MXU matmul per
+segment, masked to the probed partitions, no pointer chasing, bucketed
+static shapes. Centroid routing (one (Q, d)·(d, C) product and a stable
+argsort) and the int8 path's exact fp32 rescore stay on the host.
+Elsewhere the member scan runs on the host: per-query matvecs (fp32) or
+one integer GEMM over the probed union (int8).
 """
 from __future__ import annotations
 
@@ -38,6 +45,7 @@ class IVFIndex:
         self._vscale: np.ndarray | None = None
         self._f32_fetch = None
         self.rescore_factor = 4
+        self._dev = None                             # device scan copy
 
     # -- build ----------------------------------------------------------
     def build(self, vectors: np.ndarray) -> None:
@@ -95,6 +103,41 @@ class IVFIndex:
         assert getattr(self, "_vq8", None) is not None
         self._vectors = None
 
+    @property
+    def quantized(self) -> bool:
+        return self._vq8 is not None
+
+    @property
+    def n_rows(self) -> int:
+        return len(self._vq8 if self.quantized else self._vectors)
+
+    # -- device residency (DESIGN.md §11) ---------------------------------
+    def resident(self):
+        """The member scan's rows on the device, uploaded on first use:
+        the int8 codes and scale, or the fp32 rows, plus the partition
+        assignment. Segments are immutable, so this never changes."""
+        if self._dev is None:
+            from ..kernels.ivf_scan import upload
+            q8 = self.quantized
+            self._dev = upload(self._vq8 if q8 else self._vectors,
+                               self._assign, self.centroids.shape[0],
+                               self._vscale if q8 else None)
+        return self._dev
+
+    def free_resident(self) -> None:
+        """Release the device copy (the segment is retired)."""
+        if self._dev is not None:
+            self._dev.free()
+            self._dev = None
+
+    def members_scanned(self, probe: np.ndarray,
+                        mask: np.ndarray | None) -> int:
+        """Member rows a batch scans: for each query, the unmasked rows
+        of its probed partitions (padded rows never count)."""
+        a = self._assign if mask is None else self._assign[mask]
+        counts = np.bincount(a, minlength=self.centroids.shape[0])
+        return int(counts[probe].sum())
+
     def _member_table(self) -> np.ndarray:
         """Partition member lists as one -1-padded (C, Lmax) array, so a
         batch's candidate rows come from one fancy-index instead of a
@@ -108,10 +151,21 @@ class IVFIndex:
         return self._members
 
     # -- search -----------------------------------------------------------
+    def route(self, queries: np.ndarray, nprobe: int) -> np.ndarray:
+        """(Q, nprobe) partition ids per query: one (Q, C) centroid
+        product and a stable argsort, on the host."""
+        qp, nq = pad_queries(queries)
+        nprobe = min(nprobe, len(self._lists))
+        c_scores = qp @ self.centroids.T                  # (Q, C): routing
+        return np.argsort(-c_scores[:nq], axis=1,
+                          kind="stable")[:, :nprobe]
+
     def search(self, queries: np.ndarray, k: int = 5, nprobe: int = 8,
                mask: np.ndarray | None = None
                ) -> tuple[np.ndarray, np.ndarray, IVFStats]:
         """Batched search. Returns (scores (Q, k), row ids (Q, k), stats).
+        On the device (``kernels.ivf_scan.on_device``) this is
+        ``search_resident`` over this one index; the host path follows.
 
         Centroid routing for the whole batch is ONE matmul + one top-k;
         candidate rows for the whole batch come from one fancy-index of
@@ -126,17 +180,17 @@ class IVFIndex:
         never rank — the segmented index's deletion-vector path.
         """
         assert self.centroids is not None, "build() first"
-        qp, nq = pad_queries(queries)
-        q = qp[:nq]
-        nprobe = min(nprobe, len(self._lists))
-        c_scores = qp @ self.centroids.T                  # (Q, C): routing
-        probe = np.argsort(-c_scores[:nq], axis=1,
-                           kind="stable")[:, :nprobe]
+        from ..kernels.ivf_scan import on_device
+        if on_device():
+            return search_resident([self], queries, [k], [nprobe],
+                                   [mask])[0]
+        q = np.atleast_2d(np.asarray(queries, np.float32))
+        nq = q.shape[0]
+        probe = self.route(q, nprobe)
         out_s = np.full((nq, k), -np.inf, np.float32)
         out_i = np.full((nq, k), -1, np.int64)
-        quantized = self._vq8 is not None
-        n_rows = len(self._vq8 if quantized else self._vectors)
-        if quantized:
+        n_rows = self.n_rows
+        if self.quantized:
             scanned = self._search_q8(q, probe, mask, k, out_s, out_i)
         else:
             members = self._member_table()
@@ -239,3 +293,47 @@ class IVFIndex:
         hits = sum(len(set(approx[i]) & set(exact[i]))
                    for i in range(q.shape[0]))
         return hits / (q.shape[0] * k)
+
+
+def search_resident(indexes: list[IVFIndex], queries: np.ndarray,
+                    ks: list[int], nprobes: list[int],
+                    masks: list[np.ndarray | None]
+                    ) -> list[tuple[np.ndarray, np.ndarray, IVFStats]]:
+    """Member scans of several IVF indexes against their device-resident
+    rows in ONE dispatch and ONE wait (DESIGN.md §11). Per index: the
+    host routes the batch (``route``), the device keeps the top ``ks[j]``
+    of the probed, unmasked rows — or, for int8 rows, the rescore pool of
+    ``pool_k`` rows, scored against the scale-folded fp32 query — and the
+    host rescores that pool in exact fp32 (``rescore_topk``). Returns
+    ``search``'s (scores, ids, stats) per index."""
+    from ..index.quant import pool_k, rescore_topk
+    from ..kernels.common import to_host
+    from ..kernels.ivf_scan import ivf_scan, unpack
+    q = np.atleast_2d(np.asarray(queries, np.float32))
+    nq = q.shape[0]
+    probes = [ix.route(q, npb) for ix, npb in zip(indexes, nprobes)]
+    residents = [ix.resident() for ix in indexes]
+    scan_ks = [min(pool_k(k, ix.n_rows, ix.rescore_factor)
+                   if ix.quantized else k, r.n_pad)
+               for ix, k, r in zip(indexes, ks, residents)]
+    (res,) = to_host(ivf_scan(q, residents, probes, masks, scan_ks))
+    s_all, i_all = unpack(res)
+    out = []
+    for j, ix in enumerate(indexes):
+        k, mask = ks[j], masks[j]
+        scanned = ix.members_scanned(probes[j], mask)
+        out_s = np.full((nq, k), -np.inf, np.float32)
+        out_i = np.full((nq, k), -1, np.int64)
+        ids = i_all[j, :nq, :scan_ks[j]].astype(np.int64)
+        if not ix.quantized:
+            s = s_all[j, :nq, :scan_ks[j]]
+        elif scanned:
+            s, ids = rescore_topk(q, ids, ix._f32_fetch, k)
+        else:                      # nothing to rescore: every slot empty
+            s, ids = out_s[:, :0], out_i[:, :0]
+        out_s[:, :s.shape[1]] = s
+        out_i[:, :ids.shape[1]] = ids
+        out.append((out_s, out_i, IVFStats(len(ix._lists), ix.n_rows,
+                                           scanned / max(nq * ix.n_rows,
+                                                         1))))
+    return out

@@ -55,7 +55,7 @@ from .compaction import CompactionStats, SizeTieredCompactor
 from .manifest import Manifest
 from .memtable import Memtable
 from .quant import fixed_scale, pool_k, rescore_topk
-from .segment import Segment
+from .segment import Segment, search_ivf_resident
 
 
 class CompactionInterrupted(RuntimeError):
@@ -461,6 +461,7 @@ class SegmentedIndex:
         for v in victims:
             del self.segments[v.seg_id]
             self._seg_meta.pop(v.seg_id, None)
+            v.free_device()
         if merged is not None:
             self.segments[merged.seg_id] = merged
             mrow = 0
@@ -755,26 +756,51 @@ class SegmentedIndex:
                 scanned += obs.scan_row_reads(
                     seg_scanned, nq, per_query=False, source="solo",
                     row_bytes=self.dim * (1 if self.quantized else 4))
-        # IVF segments: batched centroid routing + per-query member scan.
+        # IVF segments: batched centroid routing on the host; the member
+        # scan on the device (every IVF segment in one dispatch and one
+        # wait) or, off the chip, on the host per segment.
+        ivf_live = {}
         for seg, sbase in cat.ivf:
             svis = (None if vis is None
                     else vis[sbase:sbase + len(seg)])
             if seg.n_alive == 0 or (svis is not None and not svis.any()):
                 continue
-            with obs.span(f"ivf_scan:{seg.seg_id}"):
-                s, rows, seg_scanned = seg.search(q, k,
-                                                  nprobe=self.nprobe,
-                                                  visible=svis)
-                s = np.asarray(s, np.float32)
-                rows = np.asarray(rows)
-                g = np.where(rows >= 0, sbase + np.clip(rows, 0, None),
-                             -1)
-                blocks_s.append(s)
-                blocks_g.append(g)
-                # per-query avg x queries (host-side member gathers)
-                scanned += obs.scan_row_reads(
-                    seg_scanned, nq, per_query=True, source="ivf",
-                    row_bytes=self.dim * (1 if self.quantized else 4))
+            ivf_live[seg.seg_id] = svis
+
+        def ivf_block(s, rows, sbase, seg_scanned):
+            rows = np.asarray(rows)
+            blocks_s.append(np.asarray(s, np.float32))
+            blocks_g.append(np.where(rows >= 0,
+                                     sbase + np.clip(rows, 0, None), -1))
+            # per-query avg x queries (member scans are per query)
+            return obs.scan_row_reads(
+                seg_scanned, nq, per_query=True, source="ivf",
+                row_bytes=self.dim * (1 if self.quantized else 4))
+
+        from ..kernels.ivf_scan import on_device
+        if ivf_live and on_device():
+            with obs.span("ivf_scan:device") as isp:
+                # every IVF segment of the catalog, live or not, so the
+                # program's shapes change only with the catalog
+                segs = [seg for seg, _ in cat.ivf]
+                masks = [seg.alive if vis is None
+                         else seg.alive & vis[b:b + len(seg)]
+                         for seg, b in cat.ivf]
+                isp.add("ivf_device_segments", len(segs))
+                res = search_ivf_resident(segs, q, k, self.nprobe, masks)
+                for (seg, sbase), (s, rows, seg_scanned) in zip(cat.ivf,
+                                                               res):
+                    if seg.seg_id in ivf_live:
+                        scanned += ivf_block(s, rows, sbase, seg_scanned)
+        else:
+            for seg, sbase in cat.ivf:
+                if seg.seg_id not in ivf_live:
+                    continue
+                with obs.span(f"ivf_scan:{seg.seg_id}"):
+                    s, rows, seg_scanned = seg.search(
+                        q, k, nprobe=self.nprobe,
+                        visible=ivf_live[seg.seg_id])
+                    scanned += ivf_block(s, rows, sbase, seg_scanned)
         self._scan_scanned += scanned
         self._scan_denom += max(len(self._by_key), 1) * nq
         if not blocks_s:
@@ -937,6 +963,8 @@ class SegmentedIndex:
     def reset(self, drop_disk: bool = True) -> None:
         with self._lock:
             self.mem.reset()
+            for seg in self.segments.values():
+                seg.free_device()
             self.segments.clear()
             self._by_key.clear()
             self._seg_meta.clear()

@@ -37,7 +37,7 @@ import numpy as np
 
 from ..core.hashing import blob_checksum, file_checksum
 from ..core.integrity import CorruptionError
-from ..core.ivf import IVFIndex
+from ..core.ivf import IVFIndex, search_resident
 from ..testing.faults import FAULTS
 from .quant import (F32Rows, data_scale, fixed_scale, mmap_f32_fetch,
                     pool_k, quantize_rows, rescore_topk)
@@ -167,6 +167,18 @@ class Segment:
         """Tombstone one row (delete or shadow-by-newer-insert)."""
         self.alive[row] = False
 
+    def free_device(self) -> None:
+        """Release the IVF scan rows' device copy (segment retired)."""
+        if self.ivf is not None:
+            self.ivf.free_resident()
+
+    def ivf_nprobe(self, nprobe: int) -> int:
+        """The partition count grows as sqrt(rows), so a fixed nprobe
+        would scan an ever smaller share of a merged segment and lose
+        recall: probe at least an eighth of the partitions (8 of the 64
+        a memtable-sized segment has)."""
+        return max(nprobe, -(-self.ivf.centroids.shape[0] // 8))
+
     def _with_alive(self, alive: np.ndarray) -> "Segment":
         """Adopt a deletion vector (format-coercion path on rebuild)."""
         self.alive = np.asarray(alive, bool).copy()
@@ -240,12 +252,8 @@ class Segment:
         mask = self.alive if visible is None else (self.alive & visible)
         n_mask = int(mask.sum())
         if self.ivf is not None:
-            # the partition count grows as sqrt(rows), so a fixed nprobe
-            # would scan an ever smaller share of a merged segment and
-            # lose recall: probe at least an eighth of the partitions
-            # (8 of the 64 a memtable-sized segment has)
-            nprobe = max(nprobe, -(-self.ivf.centroids.shape[0] // 8))
-            s, i, stats = self.ivf.search(q, k=k_eff, nprobe=nprobe,
+            s, i, stats = self.ivf.search(q, k=k_eff,
+                                          nprobe=self.ivf_nprobe(nprobe),
                                           mask=mask)
             return s, i, int(round(stats.fraction_scanned * len(self)))
         from ..core.types import pad_queries
@@ -367,3 +375,17 @@ class Segment:
                    [str(x) for x in z["doc_ids"]],
                    [str(x) for x in z["texts"]],
                    ivf_state=ivf_state, **common)
+
+
+def search_ivf_resident(segs: list[Segment], queries: np.ndarray, k: int,
+                        nprobe: int, masks: list[np.ndarray]
+                        ) -> list[tuple[np.ndarray, np.ndarray, int]]:
+    """``Segment.search`` of several IVF segments in one device dispatch
+    (``core.ivf.search_resident``); ``masks[j]`` is segment j's alive
+    (and visible) row mask. Returns (scores, rows, avg rows scanned per
+    query) per segment."""
+    res = search_resident([s.ivf for s in segs], queries,
+                          [min(k, len(s)) for s in segs],
+                          [s.ivf_nprobe(nprobe) for s in segs], masks)
+    return [(sc, rows, int(round(st.fraction_scanned * len(s))))
+            for s, (sc, rows, st) in zip(segs, res)]

@@ -34,6 +34,9 @@ Span taxonomy (stable names — DESIGN.md §12 documents the contract):
           intent:<mode>     one temporal-intent group
             fused_scan      memtable + small-segment fused dispatch
             solo_scan / ivf_scan:<seg>   per-segment scans
+            ivf_scan:device every IVF segment's member scan in one
+                            device dispatch (ivf_device_segments); an
+                            h2d child uploads a new segment's rows once
             fused_temporal  resident full-history temporal dispatch
             kernel:<name>   one kernel call: argument copy plus enqueue
                             (the host's share; device time is in the

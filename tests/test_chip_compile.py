@@ -1,4 +1,5 @@
-"""The four scan kernels of the served path, compiled for a TPU v5e.
+"""The four scan kernels of the served path, compiled for a TPU v5e, and
+the IVF member scan's plain XLA program.
 
 Each case lowers a kernel through its ``ops.py`` jit wrapper for one chip
 of a described (not attached) ``v5e:2x2`` topology and asserts that the
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.common import row_block
+from repro.kernels.ivf_scan import _ivf_scan_jit
 from repro.kernels.temporal_mask_score.ops import _temporal_topk_jit
 from repro.kernels.topk_search.ops import _topk_search_jit, _topk_search_q8_jit
 
@@ -70,3 +72,24 @@ def _lower(kernel: str, nq: int, n: int, sharding):
 def test_kernel_compiles_for_v5e(kernel, nq, n, one_chip):
     compiled = _lower(kernel, nq, n, one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp32", "int8"])
+def test_ivf_scan_compiles_for_v5e(quantized, one_chip):
+    """Two resident segments of the paper cells' size (4,096-row bucket,
+    64 partitions) against a batch of 32, as the served path calls it."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, c, nq, segs = 4096, 64, 32, 2
+    rows = tuple(sds((n, D), jnp.int8 if quantized else jnp.float32)
+                 for _ in range(segs))
+    assign = tuple(sds((n,), jnp.int32) for _ in range(segs))
+    scales = tuple(sds((D,), jnp.float32) if quantized else None
+                   for _ in range(segs))
+    k = K_POOL if quantized else K
+    compiled = _ivf_scan_jit.lower(
+        sds((nq, D), jnp.float32), rows, assign, scales,
+        sds((segs, nq, c), jnp.bool_), sds((segs * n,), jnp.bool_),
+        ks=(k,) * segs).compile()
+    assert compiled.out_info.shape == (2, segs, nq, k)

@@ -343,6 +343,14 @@ class TestQuantizedWritePath:
             assert [(r.chunk_id, r.score) for r in solo] == \
                    [(r.chunk_id, r.score) for r in batched[qi]], qi
 
+    def test_ivf_batch_equals_sequential_under_score_ties_on_device(
+            self, monkeypatch):
+        """The same ties with the IVF member scan forced onto its device
+        program (kernels/ivf_scan.py) on the CPU: pool ties go to the
+        lower row id there too."""
+        monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
+        self.test_ivf_batch_equals_sequential_under_score_ties()
+
     def test_ivf_min_rows_drift_on_reopen(self):
         """Config drift: quantized segments reopened under a RAISED
         ivf_min_rows lose their IVF and fall to the solo scan path

@@ -65,6 +65,34 @@ class TestIndexBatchParity:
         for i in range(len(q)):
             assert idx.search(q[i], k=10)[0] == batch[i]
 
+    def test_batch_equals_sequential_with_tombstones_and_segments_on_device(
+            self, monkeypatch):
+        """The same, with the IVF member scans forced onto their device
+        program (kernels/ivf_scan.py) on the CPU."""
+        monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
+        self.test_batch_equals_sequential_with_tombstones_and_segments()
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["fp32", "int8"])
+    def test_ivf_query_alone_equals_batch_of_32_on_device(self, quantized,
+                                                          monkeypatch):
+        """A served batch of 32 against IVF segments on the device path:
+        each query alone gives the same ids and the same score bits."""
+        monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
+        rng = np.random.default_rng(4)
+        dim = 64
+        idx = SegmentedIndex(dim, mem_capacity=1024, ivf_min_rows=1024,
+                             quantized=quantized)
+        v = _unit(rng, 4000, dim)
+        idx.insert(_mk_records(v))
+        idx.delete([("d", i) for i in range(0, 4000, 11)])
+        assert idx.stats()["partitioned_segments"] >= 2
+        q = (v[rng.choice(4000, 32)]
+             + 0.02 * rng.standard_normal((32, dim))).astype(np.float32)
+        batch = idx.search(q, k=10)
+        for i in range(len(q)):
+            assert idx.search(q[i], k=10)[0] == batch[i]
+
     def test_authority_arrays_match_by_key(self):
         rng = np.random.default_rng(1)
         idx = SegmentedIndex(32, mem_capacity=64, ivf_min_rows=128)
