@@ -141,11 +141,11 @@ def rescore_topk(q: np.ndarray, pool_idx: np.ndarray,
     descending, ties broken by pool order (i.e. the quantized scan's own
     rank — stable). Empty slots come back (-inf, -1).
 
-    Cost: one fetch of the UNIQUE pool rows across the whole batch plus
-    one (Q, U) matmul with U <= Q*k' — independent of corpus size. Every
-    caller's rescore runs under one ``rescore`` span, counting the unique
-    rows fetched (``rescore_rows``) and their fp32 bytes
-    (``rescore_bytes``).
+    Cost: one fetch of the U UNIQUE pool rows across the whole batch,
+    then Q*k' dot products — each query against its own pool rows only
+    — independent of corpus size. Every caller's rescore runs under one
+    ``rescore`` span, counting the unique rows fetched (``rescore_rows``)
+    and their fp32 bytes (``rescore_bytes``).
     """
     q = np.atleast_2d(np.asarray(q, np.float32))
     pool_idx = np.atleast_2d(np.asarray(pool_idx, np.int64))
@@ -170,8 +170,7 @@ def rescore_topk(q: np.ndarray, pool_idx: np.ndarray,
         # the next int8 GEMM (torch/oneDNN pool) wants the cores — that
         # ping-pong measured ~9x on the raw GEMM and ~3x on the
         # end-to-end scan on a 2-core host
-        exact = np.einsum("qd,ud->qu", q, rows)           # (Q, U)
-        s = np.take_along_axis(exact, inv.reshape(nq, kp), axis=1)
+        s = np.einsum("qd,qkd->qk", q, rows[inv.reshape(nq, kp)])  # (Q, k')
         s = np.where(pool_idx >= 0, s, -np.inf).astype(np.float32)
         order = np.argsort(-s, axis=1, kind="stable")[:, :k]
         top_s = np.take_along_axis(s, order, axis=1)

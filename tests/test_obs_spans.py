@@ -100,6 +100,14 @@ class TestFusedSplit:
         (rs,) = root.find("rescore")
         # rows 0 (an empty slot's clip), 1, 3 and 7
         assert rs.counters == {"rescore_rows": 4, "rescore_bytes": 64}
+        # eleven disjoint pools of 4 (the twelfth repeats the first)
+        pool = np.arange(48).reshape(12, 4)
+        pool[11] = pool[0]
+        rows = np.arange(200, dtype=np.float32).reshape(50, 4)
+        with obs.trace("t") as root:
+            rescore_topk(np.ones((12, 4), np.float32), pool, rows, 2)
+        (rs,) = root.find("rescore")
+        assert rs.counters == {"rescore_rows": 44, "rescore_bytes": 704}
 
 
 class _CountingAnnotation:

@@ -75,6 +75,58 @@ class TestQuantPrimitives:
                                        rtol=1e-5, atol=1e-6)
         assert i[1, 2] == -1 and np.isneginf(s[1, 2])
 
+    @pytest.mark.parametrize("case", ["disjoint", "shared", "duplicates",
+                                      "empty_slots", "k_over_live",
+                                      "ties"])
+    def test_rescore_topk_equals_all_pairs_formula(self, case):
+        d = 48
+        c = _unit((400, d), 11)
+        q = _unit((8, d), 12)
+        rng = np.random.default_rng(13)
+        k = 5
+        if case == "disjoint":                 # U = Q*k'
+            pool = rng.permutation(400)[:8 * 12].reshape(8, 12)
+        elif case == "shared":                 # U = k'
+            base = rng.permutation(400)[:12]
+            pool = np.stack([rng.permutation(base) for _ in range(8)])
+        elif case == "duplicates":
+            pool = rng.permutation(400)[:8 * 12].reshape(8, 12)
+            pool[:, 6:] = pool[:, :6]
+            pool[3] = 17
+        elif case == "empty_slots":
+            pool = rng.permutation(400)[:8 * 12].reshape(8, 12)
+            pool[rng.random(pool.shape) < 0.4] = -1
+            pool[2] = -1
+            pool[5, :11] = -1
+        elif case == "k_over_live":
+            pool = rng.permutation(400)[:8 * 12].reshape(8, 12)
+            pool[:, 3:] = -1
+            k = 9
+        else:                      # two distinct rows: scores all tie
+            c[100:148], c[148:196] = c[0], c[1]
+            pool = rng.permutation(np.arange(100, 196))[:96].reshape(8, 12)
+        pool = pool.astype(np.int64)
+        # the all-pairs formula: every query against every unique row
+        uniq, inv = np.unique(np.clip(pool, 0, None), return_inverse=True)
+        exact = np.einsum("qd,ud->qu", q, c[uniq])
+        s = np.take_along_axis(exact, inv.reshape(pool.shape), axis=1)
+        s = np.where(pool >= 0, s, -np.inf).astype(np.float32)
+        order = np.argsort(-s, axis=1, kind="stable")[:, :k]
+        want_s = np.take_along_axis(s, order, axis=1)
+        want_i = np.where(np.isfinite(want_s),
+                          np.take_along_axis(pool, order, axis=1), -1)
+        src = F32Rows(lambda rows: c[rows], d)
+        got_s, got_i = rescore_topk(q, pool, src, k)
+        np.testing.assert_array_equal(got_s, want_s)
+        np.testing.assert_array_equal(got_i, want_i)
+        assert src.rows_read == len(uniq)
+        if case == "ties":         # the tie order is the pool's order
+            assert (want_s[:, :2] == want_s[:, :1]).all()
+            pos = np.array([[list(row).index(i) for i in ids]
+                            for row, ids in zip(pool, want_i)])
+            tied = want_s[:, 1:] == want_s[:, :-1]
+            assert (pos[:, 1:] > pos[:, :-1])[tied].all()
+
     def test_f32rows_passthrough_and_stats(self):
         c = _unit((100, 16), 6)
         fetches = []
