@@ -5,7 +5,7 @@ Every scan in the system is memory-bandwidth-bound: it streams each
 corpus row once per dispatch. This suite measures, at 20k/50k rows:
 
   - SCAN throughput: the fused exact top-k scan (the memtable + small-
-    segment path) fp32 vs int8+rescore — the headline >=2x claim;
+    segment path) fp32 vs int8+rescore;
   - the TEMPORAL validity-masked scan fp32 vs int8+rescore over a
     synthetic full-history block (per-query windows, leakage asserted);
   - RESIDENT embedding bytes at the index level (memtable + segments +
@@ -13,12 +13,10 @@ corpus row once per dispatch. This suite measures, at 20k/50k rows:
   - RECALL@10 of the quantized path vs the fp32 oracle on current,
     point-in-time, and window queries (store level, gate >= 0.99).
 
-Gate semantics: the speedup gate applies only when the int8 integer-GEMM
-host path is available (kernels/qscan — torch-backed; the numpy cast
-fallback is correct but not fast, and on TPU the Pallas q8 kernel is the
-fast path instead). Smoke mode gates a lower speedup bar (1.3x at 20k on
-noisy shared CI runners); the full run gates the paper claim: >=2x at
-50k rows. Recall and bytes gates apply in BOTH modes.
+Gates: recall and bytes, in both modes. The scan and temporal rows'
+``ref_ratio`` (fp32 ms over int8 ms) is reported, not gated: off a TPU
+both scans are the jnp references of the chip's kernels, so the ratio
+times XLA's CPU backend and is no speedup of the system.
 
   PYTHONPATH=src python -m benchmarks.quantized_scan [--smoke] [--json out.json]
 """
@@ -37,7 +35,6 @@ from repro.data.corpus import generate_corpus
 from repro.index.lsm import SegmentedIndex
 from repro.index.quant import (data_scale, fixed_scale, pool_k,
                                quantize_rows, rescore_topk)
-from repro.kernels.qscan import have_int8_host
 from repro.kernels.topk_search.ops import topk_search, topk_search_q8
 from repro.kernels.temporal_mask_score.ops import (temporal_window_topk,
                                                    temporal_window_topk_q8)
@@ -99,7 +96,7 @@ def scan_point(n: int, dim: int, nq: int, k: int, rescore_factor: int,
     return {
         "n": n, "dim": dim, "nq": nq, "k": k, "pool_k": kp,
         "fp32_ms": fp32_ms, "q8_ms": q8_ms,
-        "speedup": fp32_ms / max(q8_ms, 1e-9),
+        "ref_ratio": fp32_ms / max(q8_ms, 1e-9),
         "fp32_mrows_s": n * nq / max(fp32_ms, 1e-9) / 1e3,
         "q8_mrows_s": n * nq / max(q8_ms, 1e-9) / 1e3,
         "recall_at_k": _recall(i_f, s_f, i_q, s_q),
@@ -145,7 +142,7 @@ def temporal_point(n: int, dim: int, nq: int, k: int, rescore_factor: int,
             assert vf[j] < t1s[qi] and t0s[qi] < vt[j], "temporal leakage"
     return {
         "n": n, "fp32_ms": fp32_ms, "q8_ms": q8_ms,
-        "speedup": fp32_ms / max(q8_ms, 1e-9),
+        "ref_ratio": fp32_ms / max(q8_ms, 1e-9),
         "recall_at_k": _recall(i_f, s_f, i_q, s_q),
     }
 
@@ -233,13 +230,13 @@ def run(smoke: bool = False, seed: int = 0) -> dict:
     if smoke:
         sizes, dim, nq = (20_000,), 384, 8
         bytes_n, docs, versions = 20_000, 8, 3
-        min_speedup, min_bytes = 1.3, 2.8   # noisy shared CI runners; the
-        # memtable's fixed fp32 cost is a larger share at smoke sizes
+        min_bytes = 2.8   # the memtable's fixed fp32 cost is a larger
+        # share at smoke sizes
     else:
         sizes, dim, nq = (20_000, 50_000), 384, 8
         bytes_n, docs, versions = 50_000, 20, 4
-        min_speedup, min_bytes = 2.0, 3.3   # whole-index incl fp32
-        # memtable; the scan-corpus payload itself is ~4x (gated below)
+        min_bytes = 3.3   # whole-index incl fp32 memtable; the
+        # scan-corpus payload itself is ~4x (gated below)
     k, rescore_factor = 10, 4
     scan = [scan_point(n, dim, nq, k, rescore_factor, seed) for n in sizes]
     temporal = [temporal_point(n, dim, nq, k, rescore_factor, seed)
@@ -251,9 +248,7 @@ def run(smoke: bool = False, seed: int = 0) -> dict:
                + [p["recall_at_k"] for p in temporal]
                + [store["recall_current"], store["recall_point"],
                   store["recall_window"]])
-    fast_host = have_int8_host()
     gate = {
-        "int8_host_available": fast_host,
         "min_recall": float(min(recalls)),
         "recall_pass": min(recalls) >= 0.99,
         "bytes_reduction": nbytes["bytes_reduction"],
@@ -261,18 +256,11 @@ def run(smoke: bool = False, seed: int = 0) -> dict:
         "payload_reduction": nbytes["payload_reduction"],
         "bytes_pass": (nbytes["bytes_reduction"] >= min_bytes
                        and nbytes["payload_reduction"] >= 3.9),
-        "scan_speedup_at_gate": big_scan["speedup"],
-        "temporal_speedup_at_gate": big_temporal["speedup"],
+        "scan_ref_ratio_at_gate": big_scan["ref_ratio"],
+        "temporal_ref_ratio_at_gate": big_temporal["ref_ratio"],
         "rows_at_gate": big_scan["n"],
-        "min_speedup_required": min_speedup,
-        # the speedup gate needs the integer-GEMM host path (or a TPU);
-        # the numpy fallback is a correctness path, not a fast path
-        "speedup_pass": (not fast_host
-                         or (big_scan["speedup"] >= min_speedup
-                             and big_temporal["speedup"] >= min_speedup)),
     }
-    gate["pass"] = bool(gate["recall_pass"] and gate["bytes_pass"]
-                        and gate["speedup_pass"])
+    gate["pass"] = bool(gate["recall_pass"] and gate["bytes_pass"])
     return {"scan": scan, "temporal": temporal, "bytes": nbytes,
             "store": store, "gate": gate, "smoke": smoke,
             "rescore_factor": rescore_factor, "timestamp": time.time()}
@@ -286,12 +274,13 @@ def rows_from(result: dict) -> list[tuple]:
                      f"{p['fp32_mrows_s']:.0f} Mrow/s"))
         rows.append((f"{tag}/q8_ms", p["q8_ms"],
                      f"{p['q8_mrows_s']:.0f} Mrow/s pool={p['pool_k']}"))
-        rows.append((f"{tag}/speedup", p["speedup"], "target >=2x at 50k"))
+        rows.append((f"{tag}/ref_ratio", p["ref_ratio"],
+                     "fp32 ms / int8 ms, reported, not gated"))
         rows.append((f"{tag}/recall_at_10", p["recall_at_k"],
                      "gate >=0.99"))
     for p in result["temporal"]:
         tag = f"quantized_scan/temporal_n{p['n']}"
-        rows.append((f"{tag}/speedup", p["speedup"],
+        rows.append((f"{tag}/ref_ratio", p["ref_ratio"],
                      f"fp32 {p['fp32_ms']:.2f}ms -> q8 {p['q8_ms']:.2f}ms"))
         rows.append((f"{tag}/recall_at_10", p["recall_at_k"],
                      "gate >=0.99"))
@@ -313,12 +302,11 @@ def rows_from(result: dict) -> list[tuple]:
                      s[f"recall_{name}"], "gate >=0.99"))
     g = result["gate"]
     rows.append(("quantized_scan/gate_pass", float(g["pass"]),
-                 f"scan {g['scan_speedup_at_gate']:.1f}x temporal "
-                 f"{g['temporal_speedup_at_gate']:.1f}x at "
+                 f"scan {g['scan_ref_ratio_at_gate']:.1f}x temporal "
+                 f"{g['temporal_ref_ratio_at_gate']:.1f}x at "
                  f"{g['rows_at_gate']} rows, bytes "
                  f"{g['bytes_reduction']:.1f}x, min recall "
-                 f"{g['min_recall']:.3f}, int8_host="
-                 f"{'yes' if g['int8_host_available'] else 'NO'}"))
+                 f"{g['min_recall']:.3f}"))
     return rows
 
 

@@ -17,8 +17,7 @@ def build_store(root: str, n_docs: int = 100, n_versions: int = 5,
                 seed: int = 0, device_resident: bool = False):
     corpus = generate_corpus(n_docs=n_docs, n_versions=n_versions,
                              seed=seed)
-    store = LiveVectorLake(root, dim=384,
-                           device_resident_history=device_resident)
+    store = LiveVectorLake(root, dim=384, temporal_fused=device_resident)
     for v in range(n_versions):
         ts = corpus.timestamps[v]
         for d in corpus.doc_ids():

@@ -84,13 +84,13 @@ class CompileCounter:
 def device_check() -> dict:
     """The chip, and the Pallas path on it, or nothing."""
     import jax
-    from repro.kernels.common import kernel_mode, kernel_mode_q8
+    from repro.kernels.common import kernel_mode
     dev = jax.devices()[0]
     check(dev.platform == "tpu",
           f"no TPU: JAX's first device is {dev.platform!r}")
-    modes = (kernel_mode(), kernel_mode_q8())
-    check(modes == ("pallas", "pallas"),
-          f"kernel modes resolve to {modes}, not the Pallas kernels")
+    mode = kernel_mode()
+    check(mode == "pallas",
+          f"the kernel mode resolves to {mode!r}, not the Pallas kernels")
     return {"platform": dev.platform, "kind": dev.device_kind,
             "count": len(jax.devices())}
 

@@ -6,12 +6,13 @@ graph ANN is pointer-chasing and hostile to the MXU, so the hot tier is
 backed by the LSM-style segmented index (repro.index.SegmentedIndex): a
 small mutable memtable absorbs streaming writes and is exact-scanned by
 the fused top-k kernel (kernels/topk_search); immutable IVF-partitioned
-base segments serve the bulk of the corpus sub-linearly (centroid
-routing, nprobe partitions — dense MXU matmuls, no pointer chasing); a
+base segments serve the bulk of the corpus sub-linearly: the host routes
+each query to its nprobe nearest partitions (one small product against
+the centroids), and one device dispatch scores every segment's probed
+member rows (kernels/ivf_scan, a dense matmul, no pointer chasing); a
 deterministic size-tiered compactor seals/merges segments off the query
-path. Per-query results are combined by a k-candidate top-k merge — the
-same merge a shard_map fan-out feeds (every device scores its segments;
-the global merge is tiny).
+path. The sources' candidates are combined on the host by a k-candidate
+top-k merge, as the shard fabric's planner merges its shards' answers.
 
 Write semantics match the paper: new chunk => insert; modified => old row
 tombstoned + new row inserted; deleted => tombstone. Only chunks with

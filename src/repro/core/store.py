@@ -49,18 +49,16 @@ class FaultInjected(RuntimeError):
 class LiveVectorLake:
     def __init__(self, root: str, embedder: Optional[Embedder] = None,
                  dim: int = 384, hot_capacity: int = 4096,
-                 device_resident_history: bool = True,
                  cold_checkpoint_interval: int = 8,
-                 temporal_fused: Optional[bool] = None,
+                 temporal_fused: bool = True,
                  quantized: Optional[bool] = None, rescore_factor: int = 4,
                  max_pending_ingest: Optional[int] = None):
         """``temporal_fused`` selects the cold read path: True (default)
         routes temporal queries through the fused validity-masked kernel
         over the engine's resident full-history arrays; False uses the
         paper-faithful per-snapshot NumPy fold (the reference oracle).
-        ``device_resident_history`` is the legacy alias for the same
-        switch. ``cold_checkpoint_interval``: persist a cold-tier
-        checkpoint every N commits (0 disables).
+        ``cold_checkpoint_interval``: persist a cold-tier checkpoint
+        every N commits (0 disables).
 
         ``quantized=True`` turns on the int8 scan fabric (DESIGN.md
         §11): every tier's scan streams int8 with exact fp32 rescoring
@@ -99,9 +97,7 @@ class LiveVectorLake:
                            root=os.path.join(root, "hot_index"),
                            wal=self.wal, quantized=self.quantized,
                            rescore_factor=rescore_factor)
-        fused = device_resident_history if temporal_fused is None \
-            else temporal_fused
-        self.temporal = TemporalEngine(self.cold, fused=fused,
+        self.temporal = TemporalEngine(self.cold, fused=temporal_fused,
                                        quantized=self.quantized,
                                        rescore_factor=rescore_factor)
         # results carry tenant NAMES; ids are a store-local encoding

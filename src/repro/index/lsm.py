@@ -757,28 +757,12 @@ class SegmentedIndex:
                     seg_scanned, nq, per_query=False, source="solo",
                     row_bytes=self.dim * (1 if self.quantized else 4))
         # IVF segments: batched centroid routing on the host; the member
-        # scan on the device (every IVF segment in one dispatch and one
-        # wait) or, off the chip, on the host per segment.
-        ivf_live = {}
-        for seg, sbase in cat.ivf:
-            svis = (None if vis is None
-                    else vis[sbase:sbase + len(seg)])
-            if seg.n_alive == 0 or (svis is not None and not svis.any()):
-                continue
-            ivf_live[seg.seg_id] = svis
-
-        def ivf_block(s, rows, sbase, seg_scanned):
-            rows = np.asarray(rows)
-            blocks_s.append(np.asarray(s, np.float32))
-            blocks_g.append(np.where(rows >= 0,
-                                     sbase + np.clip(rows, 0, None), -1))
-            # per-query avg x queries (member scans are per query)
-            return obs.scan_row_reads(
-                seg_scanned, nq, per_query=True, source="ivf",
-                row_bytes=self.dim * (1 if self.quantized else 4))
-
-        from ..kernels.ivf_scan import on_device
-        if ivf_live and on_device():
+        # scan on the device, every IVF segment in one dispatch and one
+        # wait
+        ivf_live = {seg.seg_id for seg, b in cat.ivf
+                    if seg.n_alive and (vis is None
+                                        or vis[b:b + len(seg)].any())}
+        if ivf_live:
             with obs.span("ivf_scan:device") as isp:
                 # every IVF segment of the catalog, live or not, so the
                 # program's shapes change only with the catalog
@@ -790,17 +774,16 @@ class SegmentedIndex:
                 res = search_ivf_resident(segs, q, k, self.nprobe, masks)
                 for (seg, sbase), (s, rows, seg_scanned) in zip(cat.ivf,
                                                                res):
-                    if seg.seg_id in ivf_live:
-                        scanned += ivf_block(s, rows, sbase, seg_scanned)
-        else:
-            for seg, sbase in cat.ivf:
-                if seg.seg_id not in ivf_live:
-                    continue
-                with obs.span(f"ivf_scan:{seg.seg_id}"):
-                    s, rows, seg_scanned = seg.search(
-                        q, k, nprobe=self.nprobe,
-                        visible=ivf_live[seg.seg_id])
-                    scanned += ivf_block(s, rows, sbase, seg_scanned)
+                    if seg.seg_id not in ivf_live:
+                        continue
+                    rows = np.asarray(rows)
+                    blocks_s.append(np.asarray(s, np.float32))
+                    blocks_g.append(np.where(
+                        rows >= 0, sbase + np.clip(rows, 0, None), -1))
+                    # per-query avg x queries (member scans are per query)
+                    scanned += obs.scan_row_reads(
+                        seg_scanned, nq, per_query=True, source="ivf",
+                        row_bytes=self.dim * (1 if self.quantized else 4))
         self._scan_scanned += scanned
         self._scan_denom += max(len(self._by_key), 1) * nq
         if not blocks_s:

@@ -165,11 +165,7 @@ def rescore_topk(q: np.ndarray, pool_idx: np.ndarray,
             rows = np.asarray(f32_rows(uniq), np.float32)
         else:
             rows = np.asarray(f32_rows, np.float32)[uniq]
-        # einsum, NOT @: the pool is tiny, and a threaded BLAS gemm
-        # here would leave OpenBLAS worker threads spinning right when
-        # the next int8 GEMM (torch/oneDNN pool) wants the cores — that
-        # ping-pong measured ~9x on the raw GEMM and ~3x on the
-        # end-to-end scan on a 2-core host
+        # pairwise: each query against its own k' pool rows only
         s = np.einsum("qd,qkd->qk", q, rows[inv.reshape(nq, kp)])  # (Q, k')
         s = np.where(pool_idx >= 0, s, -np.inf).astype(np.float32)
         order = np.argsort(-s, axis=1, kind="stable")[:, :k]

@@ -9,7 +9,9 @@ Every kernel ships three execution paths:
                  terms reflect the jnp compute graph).
 
 Select globally with REPRO_KERNEL_MODE in {auto, pallas, interpret, ref};
-"auto" = pallas on TPU backends, ref elsewhere.
+"auto" = pallas on TPU backends, ref elsewhere; the int8 scans follow
+the same policy. The IVF member scan (ivf_scan.py) is one plain-XLA
+program with no mode: a CPU host runs the program the chip runs.
 """
 from __future__ import annotations
 
@@ -26,21 +28,6 @@ def kernel_mode(override: str | None = None) -> str:
     if mode == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "ref"
     if mode not in ("pallas", "interpret", "ref"):
-        raise ValueError(f"bad kernel mode {mode!r}")
-    return mode
-
-
-def kernel_mode_q8(override: str | None = None) -> str:
-    """Mode policy for the int8 asymmetric-scan kernels. Same contract
-    as ``kernel_mode`` plus a fourth path, "host": the CPU integer-GEMM
-    scan (kernels/qscan — torch._int_mm when available, blocked numpy
-    otherwise). "auto" resolves to pallas on TPU and host elsewhere —
-    on a CPU host the q8 serving path should be the fast integer scan,
-    not the jnp oracle."""
-    mode = override or os.environ.get("REPRO_KERNEL_MODE", "auto")
-    if mode == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "host"
-    if mode not in ("pallas", "interpret", "ref", "host"):
         raise ValueError(f"bad kernel mode {mode!r}")
     return mode
 

@@ -20,10 +20,8 @@ Shapes are bucketed so a new segment size builds no new program: rows
 to a power of two >= 1,024, partitions to a power of two >= 8, queries
 to a power of two >= 2. Padded rows and queries are masked out.
 
-Mode policy: the device path runs in every mode of ``kernel_mode_q8``
-but "host", i.e. on a TPU under "auto"; elsewhere the host scans of
-core/ivf.py run, and an explicit mode (``ref``) forces this program on
-the CPU.
+The program is plain XLA and has no kernel mode: it is the one IVF
+member scan, on a TPU and off it alike (DESIGN.md §11.5).
 """
 from __future__ import annotations
 
@@ -36,16 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from .common import kernel_mode_q8, to_device
+from .common import to_device
 
 ROW_FLOOR = 1024
 PART_FLOOR = 8
 QUERY_FLOOR = 2
-
-
-def on_device() -> bool:
-    """Whether IVF member scans run here (see the module docstring)."""
-    return kernel_mode_q8() != "host"
 
 
 def bucket(n: int, floor: int) -> int:

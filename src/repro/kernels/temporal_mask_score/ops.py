@@ -16,8 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
-from ..common import (kernel_mode, kernel_mode_q8, merge_blocks, pad_to,
-                      row_block, split_i64, to_device)
+from ..common import (kernel_mode, merge_blocks, pad_to, row_block,
+                      split_i64, to_device)
 from .ref import temporal_window_topk_q8_ref, temporal_window_topk_ref
 from .temporal_mask_score import temporal_block_candidates
 
@@ -107,7 +107,7 @@ def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
     (k' = rescore_factor * k) and exactly rescore in fp32. The overlap
     filter runs before ranking in EVERY mode, so the leakage guarantee
     is identical to the fp32 path."""
-    mode = kernel_mode_q8(mode)
+    mode = kernel_mode(mode)
     with obs.span("kernel:temporal_window_topk_q8"):
         q = np.atleast_2d(np.asarray(q, np.float32))
         c8 = np.asarray(c8, np.int8)
@@ -125,13 +125,6 @@ def temporal_window_topk_q8(q, c8, scale, valid_from, valid_to, t0s, t1s,
         if mode == "ref":
             s, i = temporal_window_topk_q8_ref(qs, c8, vf, vt, t0s, t1s, k)
             return s, np.where(np.isfinite(s), i, -1)
-        if mode == "host":
-            from ..qscan import asym_scores_host, pool_topk_host
-            scores = asym_scores_host(qs, c8)
-            valid = (vf[None, :] < t1s[:, None]) \
-                & (t0s[:, None] < vt[None, :])
-            scores[~valid] = -np.inf
-            return pool_topk_host(scores, k)
         bn = row_block(int(c8.shape[0]), bn)
         valid, win = _host_words(vf, vt, t0s, t1s, bn)
         qs, c8, valid, win = to_device(

@@ -9,8 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
-from ..common import (kernel_mode, kernel_mode_q8, merge_blocks, pad_to,
-                      row_block, to_device)
+from ..common import (kernel_mode, merge_blocks, pad_to, row_block,
+                      to_device)
 from .ref import topk_search_q8_ref, topk_search_ref
 from .topk_search import topk_block_candidates
 
@@ -78,9 +78,8 @@ def topk_search_q8(q, c8, scale, mask, k: int, bn: int = 512,
     are the approximate pool scores, not the final ranking.
 
     Modes: pallas/interpret = the streaming int8 Pallas kernel; ref =
-    pure-jnp oracle; host = CPU integer-GEMM scan (kernels/qscan, auto
-    default off-TPU)."""
-    mode = kernel_mode_q8(mode)
+    pure-jnp oracle (``common.kernel_mode``)."""
+    mode = kernel_mode(mode)
     with obs.span("kernel:topk_search_q8"):
         q = np.atleast_2d(np.asarray(q, np.float32))
         c8 = np.asarray(c8, np.int8)
@@ -91,11 +90,6 @@ def topk_search_q8(q, c8, scale, mask, k: int, bn: int = 512,
                     np.zeros((q.shape[0], 0), np.int32))
         from ...index.quant import fold_scale
         qs = fold_scale(q, scale)
-        if mode == "host":
-            from ..qscan import asym_scores_host, pool_topk_host
-            scores = asym_scores_host(qs, c8)
-            scores[:, ~np.asarray(mask, bool)] = -np.inf
-            return pool_topk_host(scores, k)
         bn = row_block(int(c8.shape[0]), bn)
         qs, c8, mask = to_device((qs, np.float32), (c8, np.int8),
                                  (mask, bool))

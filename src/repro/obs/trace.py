@@ -33,7 +33,7 @@ Span taxonomy (stable names — DESIGN.md §12 documents the contract):
           embed             query embedding
           intent:<mode>     one temporal-intent group
             fused_scan      memtable + small-segment fused dispatch
-            solo_scan / ivf_scan:<seg>   per-segment scans
+            solo_scan:<seg> per-segment exact scan
             ivf_scan:device every IVF segment's member scan in one
                             device dispatch (ivf_device_segments); an
                             h2d child uploads a new segment's rows once

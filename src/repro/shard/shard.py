@@ -97,7 +97,7 @@ class ShardFabric:
                  replicas: int = 1, dim: int = 384,
                  embedder_factory=None, hot_capacity: int = 4096,
                  cold_checkpoint_interval: int = 8,
-                 temporal_fused: Optional[bool] = None,
+                 temporal_fused: bool = True,
                  quantized: Optional[bool] = None,
                  auto_resume_rebalance: bool = True,
                  shard_timeout_s: Optional[float] = None,

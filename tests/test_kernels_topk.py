@@ -13,9 +13,9 @@ from repro.kernels.temporal_mask_score.ops import (temporal_topk,
                                                    temporal_window_topk_q8)
 from repro.kernels.temporal_mask_score.ref import temporal_topk_ref
 
-# the q8 kernel modes exercised on CPU ("pallas" needs a TPU; "host" is
-# the integer-GEMM serving path, "interpret" the lowered Pallas body)
-Q8_MODES = ("ref", "interpret", "host")
+# the q8 kernel modes exercised on CPU ("pallas" needs a TPU; "ref" is
+# the jnp oracle, "interpret" the lowered Pallas body)
+Q8_MODES = ("ref", "interpret")
 
 
 def _rand(shape, seed=0):
@@ -63,8 +63,8 @@ def test_topk_ref_mode_matches_interpret():
 
 
 class TestQ8TopkKernel:
-    """ISSUE 5: edge parity for the int8 asymmetric top-k kernel across
-    ref / interpret / host modes, plus pool+rescore exactness."""
+    """Edge parity for the int8 asymmetric top-k kernel across the ref
+    and interpret modes, plus pool+rescore exactness."""
 
     @pytest.mark.parametrize("nq,n,d,k,bn", [
         (1, 256, 128, 5, 128),
@@ -125,27 +125,10 @@ class TestQ8TopkKernel:
                               np.zeros(0, bool), 5, mode=mode)
         assert np.asarray(s).shape == (2, 0)
 
-    def test_host_numpy_fallback_matches_torch_path(self, monkeypatch):
-        """The blocked cast+sgemm fallback (torch absent) must select
-        the same pool membership as the integer-GEMM path after exact
-        rescore — torch is an accelerator, never a dependency."""
-        from repro.kernels import qscan
-        q, c = _rand((3, 96), 11), _rand((400, 96), 12)
-        scale = data_scale(c)
-        c8 = quantize_rows(c, scale)
-        mask = np.ones(400, bool)
-        _, pool_fast = topk_search_q8(q, c8, scale, mask, 32, mode="host")
-        monkeypatch.setattr(qscan, "_TORCH", None)
-        assert not qscan.have_int8_host()
-        _, pool_slow = topk_search_q8(q, c8, scale, mask, 32, mode="host")
-        _, i_fast = rescore_topk(q, np.asarray(pool_fast), c, 8)
-        _, i_slow = rescore_topk(q, np.asarray(pool_slow), c, 8)
-        np.testing.assert_array_equal(i_fast, i_slow)
-
     def test_modes_agree_on_pool_membership(self):
-        """ref vs interpret must be score-identical (same math); the
-        host path (query also quantized) may perturb pool ORDER but the
-        exact rescore of each pool must agree on the final top-k."""
+        """ref vs interpret must be score-identical (same math), and the
+        exact rescore of each mode's pool must agree on the final
+        top-k."""
         q, c = _rand((3, 128), 5), _rand((700, 128), 6)
         scale = data_scale(c)
         c8 = quantize_rows(c, scale)

@@ -373,18 +373,20 @@ class TestQuantizedWritePath:
         g2 = idx2.stats()["avg_fraction_scanned"]
         assert g1 == pytest.approx(g2, rel=0.05)
 
-    def test_ivf_batch_equals_sequential_under_score_ties(self):
-        """Massive duplicate embeddings force int8 score ties across the
-        pool cut; the union-batched IVF scan must still return results
-        BIT-identical to each query running alone (the boundary-tie
-        repair is layout-independent)."""
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["fp32", "int8"])
+    def test_ivf_batch_equals_sequential_under_score_ties(self, quantized):
+        """Massive duplicate embeddings force score ties across the pool
+        cut (int8) and the top-k cut (fp32); the batched IVF scan must
+        still return results BIT-identical to each query running alone:
+        ties go to the lower row id."""
         base = _unit((60, 32), 20)
         emb = np.concatenate([np.repeat(base[:4], 40, axis=0), base[4:]])
         rs = [ChunkRecord(chunk_id=f"t{i}", doc_id=f"d{i}", position=0,
                           valid_from=1 + i, text=f"t{i}", embedding=emb[i])
               for i in range(emb.shape[0])]
         idx = SegmentedIndex(32, mem_capacity=64, ivf_min_rows=100,
-                             quantized=True)
+                             quantized=quantized)
         idx.insert(rs)
         idx.seal()
         q = np.concatenate([base[:2] + 1e-3, _unit((2, 32), 21)])
@@ -394,14 +396,6 @@ class TestQuantizedWritePath:
             solo = idx.search(q[qi][None], k=8)[0]
             assert [(r.chunk_id, r.score) for r in solo] == \
                    [(r.chunk_id, r.score) for r in batched[qi]], qi
-
-    def test_ivf_batch_equals_sequential_under_score_ties_on_device(
-            self, monkeypatch):
-        """The same ties with the IVF member scan forced onto its device
-        program (kernels/ivf_scan.py) on the CPU: pool ties go to the
-        lower row id there too."""
-        monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
-        self.test_ivf_batch_equals_sequential_under_score_ties()
 
     def test_ivf_min_rows_drift_on_reopen(self):
         """Config drift: quantized segments reopened under a RAISED

@@ -47,11 +47,14 @@ def _assert_parity(store, queries, k=3, **kw):
 
 
 class TestIndexBatchParity:
-    def test_batch_equals_sequential_with_tombstones_and_segments(self):
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["fp32", "int8"])
+    def test_batch_equals_sequential_with_tombstones_and_segments(
+            self, quantized):
         rng = np.random.default_rng(0)
         dim = 64
         idx = SegmentedIndex(dim, mem_capacity=512, nprobe=8,
-                             ivf_min_rows=1024)
+                             ivf_min_rows=1024, quantized=quantized)
         v = _unit(rng, 6000, dim)
         idx.insert(_mk_records(v))
         idx.delete([("d", i) for i in range(0, 6000, 13)])   # tombstones
@@ -65,20 +68,11 @@ class TestIndexBatchParity:
         for i in range(len(q)):
             assert idx.search(q[i], k=10)[0] == batch[i]
 
-    def test_batch_equals_sequential_with_tombstones_and_segments_on_device(
-            self, monkeypatch):
-        """The same, with the IVF member scans forced onto their device
-        program (kernels/ivf_scan.py) on the CPU."""
-        monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
-        self.test_batch_equals_sequential_with_tombstones_and_segments()
-
     @pytest.mark.parametrize("quantized", [False, True],
                              ids=["fp32", "int8"])
-    def test_ivf_query_alone_equals_batch_of_32_on_device(self, quantized,
-                                                          monkeypatch):
-        """A served batch of 32 against IVF segments on the device path:
-        each query alone gives the same ids and the same score bits."""
-        monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
+    def test_ivf_query_alone_equals_batch_of_32(self, quantized):
+        """A served batch of 32 against IVF segments: each query alone
+        gives the same ids and the same score bits."""
         rng = np.random.default_rng(4)
         dim = 64
         idx = SegmentedIndex(dim, mem_capacity=1024, ivf_min_rows=1024,
