@@ -312,15 +312,33 @@ class LiveVectorLake:
         AND-ed into the scan validity masks PRE-ranking on every path
         (DESIGN.md §14). None = unscoped (legacy behavior, bit-
         identical results). Unknown names fail CLOSED (empty set)."""
+        return self.search_batch(texts, None, k=k, at=at, window=window,
+                                 visibility=visibility)
+
+    def search_batch(self, texts: Sequence[str],
+                     vecs: Optional[np.ndarray], k: int = 5,
+                     at: Optional[int] = None,
+                     window: Optional[tuple[int, int]] = None,
+                     visibility: Visibility = None
+                     ) -> list[list[SearchResult]]:
+        """``query_batch`` on queries embedded upstream: ``vecs`` is the
+        (Q, dim) matrix of ``texts`` from this lake's embedding function
+        (the fabric's planner embeds a batch once for every shard,
+        DESIGN.md §10.3); ``texts`` still carry each query's temporal
+        intent. ``vecs=None`` embeds here, under an ``embed`` span."""
         if not texts:
             return []
+        if vecs is not None and np.shape(vecs) != (len(texts), self.dim):
+            raise ValueError(f"vecs has shape {np.shape(vecs)}, expected "
+                             f"({len(texts)}, {self.dim})")
         with span("store:query_batch") as sp:
             t_store = time.perf_counter()
             visible = self.tenants.visible_tids(visibility)
             intents = [classify_query(t, at=at, window=window)
                        for t in texts]
-            with span("embed"):
-                vecs = self.embedder.embed(list(texts))
+            if vecs is None:
+                with span("embed"):
+                    vecs = self.embedder.embed(list(texts))
             groups: dict[tuple, list[int]] = {}
             for i, it in enumerate(intents):
                 groups.setdefault((it.mode, it.at, it.window), []).append(i)

@@ -28,9 +28,12 @@ Span taxonomy (stable names — DESIGN.md §12 documents the contract):
 
   batch                     batcher dispatch (trace root)
     plan                    scatter-gather planner pass
+      embed                 the batch's query embedding, once for
+                            every shard (queries)
       shard:<id>            one shard's engine pass
         store:query_batch   store-level batched query
-          embed             query embedding
+          embed             query embedding (a lone lake's query_batch
+                            only: shards get the planner's vectors)
           intent:<mode>     one temporal-intent group
             fused_scan      memtable + small-segment fused dispatch
             solo_scan:<seg> per-segment exact scan

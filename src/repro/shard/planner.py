@@ -1,7 +1,8 @@
 """Scatter-gather query planner (DESIGN.md §10.3).
 
-One fabric query fans the whole (Q, d) batch to every ring shard —
-each shard runs its normal batched engine pass (hot fused top-k for
+One fabric query embeds its batch once (``embed`` under ``plan``) and
+fans the texts and their (Q, d) block to every ring shard — each shard
+runs its normal batched engine pass on that block (hot fused top-k for
 CURRENT, the fused temporal kernel over its own cold-tier resident
 history for HISTORICAL/COMPARATIVE) — and the per-shard top-k blocks
 are merged by the SAME ``merge_topk_candidates`` primitive the
@@ -163,8 +164,11 @@ class ScatterGatherPlanner:
         self._pool = None              # lazy, parallel scatter only
 
     # ------------------------------------------------------------------
-    def _one_shard(self, s: str, texts, k, at, window, visibility=None):
-        """One shard's engine pass with bounded retry: transient faults
+    def _one_shard(self, s: str, texts, vecs, k, at, window,
+                   visibility=None):
+        """One shard's engine pass over the batch's texts and their
+        embeddings (computed once by ``query_batch`` for every shard),
+        with bounded retry: transient faults
         (the chaos suite arms them at ``shard:<id>:query``) back off
         exponentially for up to ``shard_retries`` re-attempts before the
         shard counts as failed for this gather. ``visibility`` travels
@@ -181,7 +185,7 @@ class ScatterGatherPlanner:
                 FAULTS.check(f"shard:{s}:query")
                 return self.fabric.lake(s).query_batch(
                     texts, k=k, at=at, window=window,
-                    visibility=visibility)
+                    visibility=visibility, vecs=vecs)
             except Exception as e:  # noqa: BLE001 — shard fault domain
                 last = e
         raise last
@@ -197,12 +201,17 @@ class ScatterGatherPlanner:
         if degraded_ok is None:
             degraded_ok = self.degraded_ok
         with span("plan") as plan_sp:
+            # one embedding for every shard: the lakes share the
+            # fabric's embedder, so each would compute this same matrix
+            with span("embed") as emb_sp:
+                vecs = self.fabric.embedder.embed(list(texts))
+                emb_sp.add("queries", len(texts))
             ring = self.fabric.ring
             per_shard: dict[str, list[list[SearchResult]]] = {}
             failures: dict[str, Exception] = {}
             if self.shard_timeout_s is not None \
                     or deadline_at() is not None:
-                self._scatter_parallel(ring, texts, k, at, window,
+                self._scatter_parallel(ring, texts, vecs, k, at, window,
                                        per_shard, failures, plan_sp,
                                        visibility=visibility)
             else:
@@ -212,7 +221,7 @@ class ScatterGatherPlanner:
                     with span(f"shard:{s}"):
                         try:
                             per_shard[s] = self._one_shard(
-                                s, texts, k, at, window,
+                                s, texts, vecs, k, at, window,
                                 visibility=visibility)
                         except Exception as e:  # noqa: BLE001
                             failures[s] = e
@@ -267,7 +276,7 @@ class ScatterGatherPlanner:
             }
             return self._merge(texts, per_shard, k)
 
-    def _scatter_parallel(self, ring, texts, k, at, window,
+    def _scatter_parallel(self, ring, texts, vecs, k, at, window,
                           per_shard: dict, failures: dict,
                           plan_sp, visibility=None) -> None:
         """Thread-pool scatter with a bounded reply window per gather:
@@ -292,7 +301,7 @@ class ScatterGatherPlanner:
 
         def one(s: str):
             with subtrace(f"shard:{s}") as sroot:
-                return self._one_shard(s, texts, k, at, window,
+                return self._one_shard(s, texts, vecs, k, at, window,
                                        visibility=visibility), sroot
 
         futs = {s: self._pool.submit(one, s) for s in ring.shards}

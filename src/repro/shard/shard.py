@@ -32,6 +32,7 @@ import threading
 import time
 from typing import Optional, Sequence
 
+from ..core.embedder import HashProjectionEmbedder
 from ..core.store import LiveVectorLake
 from ..core.types import CDCSummary, SearchResult
 from .manifest import FabricManifest
@@ -76,9 +77,14 @@ class ShardLake:
     def query_batch(self, texts: Sequence[str], k: int = 5,
                     at: Optional[int] = None,
                     window: Optional[tuple[int, int]] = None,
-                    visibility=None) -> list[list[SearchResult]]:
-        return self.store.query_batch(texts, k=k, at=at, window=window,
-                                      visibility=visibility)
+                    visibility=None,
+                    vecs=None) -> list[list[SearchResult]]:
+        """The shard's one query entry point. ``vecs``: the texts'
+        embeddings, computed once by the planner for every shard
+        (``LiveVectorLake.search_batch``); None embeds them here."""
+        return self.store.search_batch(texts, vecs, k=k, at=at,
+                                       window=window,
+                                       visibility=visibility)
 
     # -- introspection -------------------------------------------------
     @property
@@ -110,13 +116,15 @@ class ShardFabric:
         / ``vnodes`` / ``replicas`` are ignored in favor of the persisted
         ring, and a pending migration is resumed (roll-forward) unless
         ``auto_resume_rebalance=False``. ``embedder_factory()`` builds
-        one embedder per shard lake (default: the deterministic
-        hash-projection embedder, identical across shards and to the
-        single-lake oracle)."""
+        the fabric's one embedder (default: the deterministic
+        hash-projection embedder, identical to the single-lake
+        oracle's). Every shard lake embeds with that same instance and
+        the planner embeds each query batch with it once, so a query
+        vector and the rows it is scored against come from one
+        function."""
         self.root = root
         os.makedirs(root, exist_ok=True)
         self.manifest = FabricManifest(root)
-        self.embedder_factory = embedder_factory
         self._lake_kwargs = dict(
             dim=dim, hot_capacity=hot_capacity,
             cold_checkpoint_interval=cold_checkpoint_interval,
@@ -147,6 +155,9 @@ class ShardFabric:
             self.manifest.commit({"ring": state["ring"],
                                   "transition": state.get("transition"),
                                   "lake": self._persisted_lake_config()})
+        self.embedder = (embedder_factory() if embedder_factory
+                         else HashProjectionEmbedder(
+                             dim=self._lake_kwargs["dim"]))
         self.ring = HashRing.from_dict(state["ring"])
         self._lakes: dict[str, ShardLake] = {}
         # parallel scatter workers open lakes lazily from pool threads
@@ -163,7 +174,7 @@ class ShardFabric:
     def _persisted_lake_config(self) -> dict:
         # dim/capacity/checkpointing/quantization persist (reopening must
         # not depend on the caller remembering them — a quantized shard's
-        # segments are quantized ON DISK); embedder_factory and
+        # segments are quantized ON DISK); the embedder and
         # temporal_fused stay per-process (not serializable / a debug
         # switch)
         return {k: self._lake_kwargs[k]
@@ -195,10 +206,8 @@ class ShardFabric:
             with self._lake_lock:
                 lk = self._lakes.get(shard_id)
                 if lk is None:
-                    embedder = (self.embedder_factory()
-                                if self.embedder_factory else None)
                     lk = ShardLake(shard_id, self.shard_dir(shard_id),
-                                   embedder=embedder,
+                                   embedder=self.embedder,
                                    **self._lake_kwargs)
                     self._lakes[shard_id] = lk
                     self._last_ts = max(self._last_ts,
